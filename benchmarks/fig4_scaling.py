@@ -59,7 +59,9 @@ def _run_measured(code: str, results: dict, tag: str):
     """Run a measured leg in the 8-device subprocess (XLA_FLAGS and
     PYTHONPATH extended, never clobbered — see ``forced_host_devices_env``);
     the child reports its jax.device_count() and anything != 8 is a hard
-    failure — never silently time a 1-device run."""
+    failure — never silently time a 1-device run.  A failed leg is
+    recorded under ``results[tag]["error"]`` and makes :func:`main` exit
+    non-zero once the artifact is written."""
     out = subprocess.run([sys.executable, "-c", code],
                          env=forced_host_devices_env(N_DEV),
                          capture_output=True, text=True, timeout=560)
@@ -400,6 +402,10 @@ def main(argv=None):
     with open(args.out, "w") as f:
         json.dump(results, f, indent=1, sort_keys=True)
     print(f"# wrote {args.out}")
+    failed = sorted(k for k, v in results.items()
+                    if isinstance(v, dict) and "error" in v)
+    if failed:
+        raise SystemExit(f"fig4: measured legs failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

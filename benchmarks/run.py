@@ -24,6 +24,8 @@ import jax
 
 jax.config.update("jax_enable_x64", True)   # paper-faithful f64 ladder
 
+from repro.jax_compat import use_compile_cache  # noqa: E402
+
 
 def _registry():
     from . import (fig1_sbgemv, fig2_phase_breakdown, fig3_pareto,
@@ -95,4 +97,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -26,6 +26,7 @@ import numpy as np  # noqa: E402
 from repro.core import (FFTMatvec, GaussianInverseProblem,  # noqa: E402
                         PrecisionConfig, heat_equation_p2o, rel_l2)
 from repro import solvers  # noqa: E402
+from repro.jax_compat import use_compile_cache  # noqa: E402
 
 
 def main():
@@ -128,4 +129,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

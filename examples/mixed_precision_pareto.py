@@ -22,6 +22,7 @@ from repro.core import (FFTMatvec, all_configs, format_table,  # noqa: E402
                         measure_configs, optimal_config, pareto_front,
                         random_unrepresentable)
 from repro.tune import TimingHarness, autotune  # noqa: E402
+from repro.jax_compat import use_compile_cache  # noqa: E402
 
 
 def run(levels, tol, title, exhaustive=False):
@@ -65,4 +66,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

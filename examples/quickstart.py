@@ -14,6 +14,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.backend import DispatchTable, current_backend  # noqa: E402
 from repro.core import (ExecOpts, FFTMatvec, PrecisionConfig,  # noqa: E402
                         dense_matvec, random_block_column, rel_l2)
+from repro.jax_compat import use_compile_cache  # noqa: E402
 
 
 def main():
@@ -57,4 +58,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -15,6 +15,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.models import api
 from repro.runtime import Request, ServeEngine
+from repro.jax_compat import use_compile_cache
 
 
 def main():
@@ -55,4 +56,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -15,6 +15,7 @@ from repro.checkpoint import Checkpointer
 from repro.configs import get_smoke_config
 from repro.data import SyntheticPipeline
 from repro.runtime import Trainer, TrainerConfig
+from repro.jax_compat import use_compile_cache
 
 
 def main():
@@ -57,4 +58,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -18,7 +18,7 @@ import json
 import sys
 from typing import Dict, List, Tuple
 
-from repro.backend import known_backends
+from repro.backend import known_backends, resolve_backend
 from repro.configs.fftmatvec_paper import PAPER_SINGLE, SMOKE
 from repro.core.pipeline import ExecOpts, Plan, gram_plan, matvec_plan
 from repro.core.precision import PrecisionConfig
@@ -61,8 +61,11 @@ def run_sweep(backends, configs, dims, plans=None,
     rows = []
     for backend in backends:
         opts = ExecOpts(backend=backend)
+        ladder = resolve_backend(backend).ladder()
         for cfg_s in configs:
             cfg = PrecisionConfig.from_string(cfg_s)
+            if not set(cfg.levels()) <= set(ladder):
+                continue        # refused there (UnsupportedOnBackend)
             for name, (plan, extra) in plan_matrix(cfg).items():
                 if plans is not None and name not in plans:
                     continue

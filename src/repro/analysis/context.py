@@ -248,7 +248,7 @@ class PlanContext:
         h = f
         for ax in self.bound_axes:     # bind slow first; minor ends outermost
             h = jax.vmap(h, axis_name=ax)
-        with jax.experimental.enable_x64(), \
+        with jax.enable_x64(True), \
                 pipeline.record_stages() as counters:
             jx = jax.make_jaxpr(h)(x, *planes)
         return jx, collections.Counter(counters)
@@ -293,7 +293,7 @@ class PlanContext:
         h = f
         for ax in axes:
             h = jax.vmap(h, axis_name=ax)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             return jax.make_jaxpr(h)(x)
 
 

@@ -2,6 +2,7 @@
 
 Public API:
     BackendSpec, UnsupportedOnBackend        — capability description
+    TPU_PEAKS / UnknownDevice                — per-kind TPU roofline peaks
     TPU_PALLAS / GPU_PALLAS / CPU_XLA /
     CPU_INTERPRET / XLA_REF                  — built-in specs
     current_backend / probe_backend /
@@ -15,7 +16,7 @@ Public API:
 
 from .spec import (BackendSpec, UnsupportedOnBackend,  # noqa: F401
                    BUILTIN_SPECS, CPU_INTERPRET, CPU_XLA, GPU_PALLAS,
-                   TPU_PALLAS, XLA_REF)
+                   TPU_PALLAS, TPU_PEAKS, UnknownDevice, XLA_REF)
 from .registry import (BACKEND_ENV, current_backend, known_backends,  # noqa: F401
                        probe_backend, register_backend, resolve_backend,
                        use_backend)

@@ -21,7 +21,8 @@ from typing import Iterator, Optional, Union
 
 import jax
 
-from .spec import BUILTIN_SPECS, CPU_XLA, GPU_PALLAS, TPU_PALLAS, BackendSpec
+from .spec import (BUILTIN_SPECS, CPU_XLA, GPU_PALLAS, NO_PEAKS, TPU_PALLAS,
+                   TPU_PEAKS, BackendSpec)
 
 BACKEND_ENV = "REPRO_BACKEND"
 
@@ -29,7 +30,7 @@ _REGISTRY: dict[str, BackendSpec] = dict(BUILTIN_SPECS)
 _PROBED: Optional[BackendSpec] = None        # once-per-process probe cache
 _OVERRIDE: Optional[BackendSpec] = None      # use_backend() scope
 
-_PLATFORM_SPECS = {"tpu": TPU_PALLAS, "gpu": GPU_PALLAS}
+_PLATFORM_SPECS = {"tpu": TPU_PALLAS, "gpu": GPU_PALLAS, "cpu": CPU_XLA}
 
 
 def register_backend(spec: BackendSpec) -> BackendSpec:
@@ -43,14 +44,19 @@ def known_backends() -> tuple[str, ...]:
 
 
 def _bind_device(spec: BackendSpec, device=None) -> BackendSpec:
-    """Fill platform/device_kind from the actual device where unset."""
+    """Fill platform/device_kind from the actual device where unset.  A
+    TPU spec bound to a real TPU takes that kind's roofline peaks from
+    :data:`repro.backend.spec.TPU_PEAKS`; an unknown kind binds none, so
+    it runs but its roofline is refused (``BackendSpec.roofline_peaks``)."""
     if spec.platform and spec.device_kind:
         return spec
     device = device or jax.devices()[0]
+    kind = spec.device_kind or getattr(device, "device_kind", "")
+    peaks = TPU_PEAKS.get(kind, NO_PEAKS) if (
+        spec.platform == "tpu" and device.platform == "tpu") else {}
     return dataclasses.replace(
-        spec,
-        platform=spec.platform or device.platform,
-        device_kind=spec.device_kind or getattr(device, "device_kind", ""))
+        spec, platform=spec.platform or device.platform, device_kind=kind,
+        **peaks)
 
 
 def probe_backend(device=None) -> BackendSpec:
@@ -63,7 +69,12 @@ def probe_backend(device=None) -> BackendSpec:
     if env:
         return resolve_backend(env, device=device)
     device = device or jax.devices()[0]
-    spec = _PLATFORM_SPECS.get(device.platform, CPU_XLA)
+    try:
+        spec = _PLATFORM_SPECS[device.platform]
+    except KeyError:
+        raise ValueError(
+            f"no backend spec for platform {device.platform!r}; register "
+            f"one and select it with {BACKEND_ENV}") from None
     return _bind_device(spec, device)
 
 

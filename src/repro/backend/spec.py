@@ -45,6 +45,12 @@ class BackendSpec:
     bypassing both Pallas and the traffic-fused XLA formulations — the
     numerical ground truth every other backend is compared against.
 
+    ``f64`` says the backend runs f64 phases at all: the "d" rung of the
+    precision ladder.  A TPU has no f64 FFT and no f64 Pallas datapath,
+    so a plan with a "d" stage raises :class:`UnsupportedOnBackend` there
+    (``core.pipeline.run_stages``) instead of failing in the compiler or,
+    with x64 off, silently computing in f32.
+
     ``sublane``/``lane`` are the padding alignments the kernel wrappers
     must honor; ``peak_flops``/``hbm_bandwidth``/``link_bandwidth`` feed
     the roofline model (``launch.roofline``); ``default_block_n``/
@@ -69,6 +75,7 @@ class BackendSpec:
     pallas: bool = False
     pallas_interpret: bool = False
     pallas_f64: bool = False
+    f64: bool = True
     reference: bool = False
     tile_precision: bool = False
     sublane: int = 8
@@ -84,6 +91,31 @@ class BackendSpec:
         """Stable identity for cache keys: backend + hardware it bound to."""
         return f"{self.name}@{self.platform}:{self.device_kind}"
 
+    def ladder(self) -> tuple[str, ...]:
+        """The precision levels this backend runs, highest first: d/s/h,
+        or s/h without an f64 datapath.  The plan check, the set-up
+        precision, autotune's default ladder and the lint sweep read it."""
+        return ("d", "s", "h") if self.f64 else ("s", "h")
+
+    @property
+    def setup_dtype(self):
+        """Compute dtype of the Phase-0 set-up FFT: the top of the ladder
+        (f64 canonicalizes to f32 where x64 is off)."""
+        return jnp.float64 if self.f64 else jnp.float32
+
+    def roofline_peaks(self) -> tuple[float, float, float]:
+        """``(peak_flops, hbm_bandwidth, link_bandwidth)``, or
+        :class:`UnknownDevice` for a spec bound to a device whose peaks
+        are not known: a roofline share against zero or borrowed peaks
+        would be wrong."""
+        peaks = (self.peak_flops, self.hbm_bandwidth, self.link_bandwidth)
+        if not all(peaks):
+            raise UnknownDevice(
+                f"no roofline peaks for {self.fingerprint()!r}; add the "
+                f"published peaks of device kind {self.device_kind!r} to "
+                f"repro.backend.spec.TPU_PEAKS (known: {sorted(TPU_PEAKS)})")
+        return peaks
+
     def pallas_supports(self, *dtypes) -> bool:
         """Whether the Pallas kernels can consume these dtypes here."""
         if not self.pallas:
@@ -93,20 +125,41 @@ class BackendSpec:
         return True
 
 
+class UnknownDevice(ValueError):
+    """A roofline was asked of a spec bound to a TPU whose ``device_kind``
+    has no entry in :data:`TPU_PEAKS`.  The operator still runs there;
+    only its roofline shares are refused."""
+
+
+# Published per-chip peaks of each TPU generation, keyed by the
+# ``device_kind`` JAX reports.  TPU v5e: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect over 4 links (50 GB/s per link).
+TPU_PEAKS = {
+    "TPU v5 lite": dict(peak_flops=197e12, hbm_bandwidth=819e9,
+                        link_bandwidth=50e9),
+}
+
+# what a TPU of a kind missing from TPU_PEAKS binds: no peaks, so
+# BackendSpec.roofline_peaks refuses it
+NO_PEAKS = dict(peak_flops=0.0, hbm_bandwidth=0.0, link_bandwidth=0.0)
+
+
 # ---------------------------------------------------------------------------
-# Built-in specs.  Roofline peaks: TPU v5e-class (matches the dry-run
-# constants this repo has always modeled against); GPU numbers are
-# MI300X-class, the paper's newest target.  CPU peaks are order-of-
-# magnitude placeholders — CPU runs are validation, never the roofline.
+# Built-in specs.  TPU_PALLAS carries the v5e peaks as the modeled target
+# (what compile-only roofline estimates price against); a spec bound to a
+# real TPU takes its peaks from TPU_PEAKS by device kind
+# (``registry._bind_device``).  GPU numbers are MI300X-class, the paper's
+# newest target.  CPU peaks are order-of-magnitude placeholders — CPU
+# runs are validation, never the roofline.
 # ---------------------------------------------------------------------------
 
 TPU_PALLAS = BackendSpec(
     name="tpu-pallas", platform="tpu", pallas=True, pallas_f64=False,
-    tile_precision=True,
-    peak_flops=197e12, hbm_bandwidth=819e9, link_bandwidth=50e9)
+    f64=False, tile_precision=True, **TPU_PEAKS["TPU v5 lite"])
 
 # pallas=False: the SBGEMV/SBGEMM kernels lower through the TPU Mosaic
-# pipeline (kernels/_compat.py builds pltpu CompilerParams) and do not
+# pipeline (pltpu CompilerParams) and do not
 # run on the Triton backend yet — GPU auto-dispatch takes the traffic-
 # fused XLA path; flip this when a GPU build of the kernels lands.
 # tile_precision=False for the same reason: the tiled kernels are Mosaic

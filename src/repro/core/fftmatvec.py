@@ -37,13 +37,16 @@ and ``mesh="auto"`` picks the grid itself via :func:`choose_grid`
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.backend import resolve_backend
 from repro.jax_compat import shard_map
 from . import pipeline
 from . import precision as prec
@@ -60,6 +63,11 @@ def _as_axes(axis: AxisSpec) -> Tuple[str, ...]:
     if axis is None or axis == ():
         return ()
     return (axis,) if isinstance(axis, str) else tuple(axis)
+
+
+def _axis_or_none(axis: AxisSpec) -> AxisSpec:
+    """A PartitionSpec entry for a grid axis: None when the grid has none."""
+    return axis if axis not in ((), None) else None
 
 
 def _auto_mesh(p_shape: Tuple[int, int, int], row_axis, col_axis,
@@ -135,6 +143,14 @@ def _local_gram(F_re, F_im, v, N_t: int, cfg: PrecisionConfig,
 # Public operator
 # ---------------------------------------------------------------------------
 
+# A pytree whose leaves are the F_hat planes: a jitted function takes the
+# operator as an argument, so the planes are inputs of the program and
+# never constants baked into it (GBs at the paper shape).
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["F_hat_re", "F_hat_im"],
+    meta_fields=["N_t", "precision", "opts", "mesh", "row_axis", "col_axis",
+                 "comm_level", "collective"])
 @dataclasses.dataclass
 class FFTMatvec:
     """Block-triangular Toeplitz matvec operator.
@@ -193,17 +209,22 @@ class FFTMatvec:
                 raise ValueError(f"unknown mesh spec {mesh!r}")
             mesh = _auto_mesh(F_col.shape, row_axis, col_axis,
                               devices=devices, grid=grid, net=net)
-        F_re, F_im = fourier_block_column(
-            F_col, dtype=prec.real_dtype(precision.gemv))
-        op = cls(F_re, F_im, F_col.shape[0], precision, opts, mesh,
-                 row_axis, col_axis, comm_level, collective)
-        if mesh is not None:
-            spec = P(None, op._row, op._col)
-            op = dataclasses.replace(
-                op,
-                F_hat_re=jax.device_put(F_re, NamedSharding(mesh, spec)),
-                F_hat_im=jax.device_put(F_im, NamedSharding(mesh, spec)))
-        return op
+        # set-up runs at the top of the backend's ladder (a TPU has no f64
+        # FFT: there it is f32, whatever x64 says)
+        setup = functools.partial(
+            fourier_block_column, dtype=prec.real_dtype(precision.gemv),
+            compute_dtype=resolve_backend(opts.backend).setup_dtype)
+        if mesh is None:
+            F_re, F_im = setup(F_col)
+        else:
+            # each device transforms its own (row, col) shard of F_col in
+            # place: F_hat is never assembled on one device
+            spec = P(None, _axis_or_none(row_axis), _axis_or_none(col_axis))
+            F_re, F_im = jax.jit(shard_map(
+                setup, mesh=mesh, in_specs=spec,
+                out_specs=(spec, spec)))(F_col)
+        return cls(F_re, F_im, F_col.shape[0], precision, opts, mesh,
+                   row_axis, col_axis, comm_level, collective)
 
     def with_precision(self, precision: PrecisionConfig) -> "FFTMatvec":
         """Same operator retuned to another per-phase config.
@@ -293,11 +314,11 @@ class FFTMatvec:
     @property
     def _row(self):
         """Row axis spec (None for the paper's p_r = 1 regime)."""
-        return self.row_axis if self.row_axis not in ((), None) else None
+        return _axis_or_none(self.row_axis)
 
     @property
     def _col(self):
-        return self.col_axis if self.col_axis not in ((), None) else None
+        return _axis_or_none(self.col_axis)
 
     def grid_shape(self) -> tuple[int, int]:
         """(p_r, p_c) of the mesh grid — (1, 1) when single-device.
@@ -426,16 +447,16 @@ class FFTMatvec:
         chunked writes this lets XLA reuse the input allocation for the
         assembled output instead of holding both live — the caller must
         not reuse the argument afterwards."""
-        dn = (0,) if donate else ()
-        return (jax.jit(self.matvec, donate_argnums=dn),
-                jax.jit(self.rmatvec, donate_argnums=dn))
+        dn = (1,) if donate else ()
+        return tuple(functools.partial(jax.jit(f, donate_argnums=dn), self)
+                     for f in (FFTMatvec.matvec, FFTMatvec.rmatvec))
 
     def jitted_block(self, donate: bool = False):
         """Jit-compiled (matmat, rmatmat) pair (``donate`` as in
         :meth:`jitted`)."""
-        dn = (0,) if donate else ()
-        return (jax.jit(self.matmat, donate_argnums=dn),
-                jax.jit(self.rmatmat, donate_argnums=dn))
+        dn = (1,) if donate else ()
+        return tuple(functools.partial(jax.jit(f, donate_argnums=dn), self)
+                     for f in (FFTMatvec.matmat, FFTMatvec.rmatmat))
 
     # -- sharding helpers -------------------------------------------------------
     def m_sharding(self, stacked: bool = False):
@@ -460,7 +481,6 @@ def phase_callables(op: FFTMatvec, adjoint: bool = False):
     plan into phase groups (the reorders time with the gemv they wrap,
     matching the paper's breakdown)."""
     plan = pipeline.matvec_plan(op.precision, adjoint=adjoint)
-    operands = {"F": (op.F_hat_re, op.F_hat_im)}
     N_t, opts, io_dtype = op.N_t, op.opts, op.io_dtype
     # group by stage kind (reorders attach to the gemv they wrap), robust
     # to the plan's exact stage order
@@ -470,10 +490,11 @@ def phase_callables(op: FFTMatvec, adjoint: bool = False):
               for name in ("pad", "fft", "gemv", "ifft", "reduce")}
 
     def make(stages, final: bool):
-        def f(x):
-            y = pipeline.run_stages(stages, x, operands, N_t=N_t, opts=opts)
+        def f(F_re, F_im, x):      # the planes as arguments, not constants
+            y = pipeline.run_stages(stages, x, {"F": (F_re, F_im)}, N_t=N_t,
+                                    opts=opts)
             return y.astype(io_dtype) if final else y
-        return jax.jit(f)
+        return functools.partial(jax.jit(f), op.F_hat_re, op.F_hat_im)
 
     return {name: make(stages, final=(name == "reduce"))
             for name, stages in groups.items()}

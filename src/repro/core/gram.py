@@ -37,6 +37,7 @@ contraction and stays single-device).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -50,6 +51,9 @@ from .fftmatvec import FFTMatvec, _as_axes
 from .precision import PrecisionConfig
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["op", "G_hat_re", "G_hat_im"],
+                   meta_fields=["space", "mode"])
 @dataclasses.dataclass
 class GramOperator:
     """One-pipeline Gram action, built by :meth:`FFTMatvec.gram`.
@@ -212,8 +216,9 @@ class GramOperator:
     __call__ = apply
 
     def jitted(self):
-        """Jit-compiled apply."""
-        return jax.jit(self.apply)
+        """Jit-compiled apply (the operator's planes are arguments of the
+        program, not constants)."""
+        return functools.partial(jax.jit(GramOperator.apply), self)
 
     def v_sharding(self, stacked: bool = False):
         """Sharding of the Gram's in/out block vectors on the mesh."""

@@ -41,8 +41,8 @@ from typing import Iterator, Mapping, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.backend import (BackendSpec, DispatchTable, default_table,
-                           resolve_backend)
+from repro.backend import (BackendSpec, DispatchTable, UnsupportedOnBackend,
+                           default_table, resolve_backend)
 from repro.kernels import ops as kops
 from . import precision as prec
 from .precision import PrecisionConfig
@@ -129,8 +129,9 @@ class ExecOpts:
                              f"or None, got {ov!r}")
 
     def resolve(self) -> "ResolvedOpts":
-        """Bind to the concrete backend (probe happens here, at lowering
-        time — never at operator construction)."""
+        """Bind to the concrete backend (the probe happens here, at
+        lowering time; operator construction reads only the spec's
+        ``setup_dtype``)."""
         spec = resolve_backend(self.backend)
         table = self.dispatch if self.dispatch is not None \
             else default_table(spec)
@@ -299,7 +300,7 @@ def _gemv(stage, x, operands, N_t, S, opts):
     x_re, x_im = (p.astype(dt) for p in x)
     # stage-level dispatch: a forced-Pallas preference relaxes to auto for
     # levels the backend's Pallas cannot run (d stages of the paper ladder
-    # on TPU keep flowing through XLA, exactly as before)
+    # on the CPU interpreter keep flowing through XLA)
     table = opts.table.for_dtype(dt, opts.spec)
     if S == 1:
         return kops.sbgemv(A_re.astype(dt), A_im.astype(dt), x_re, x_im,
@@ -627,6 +628,30 @@ def stage_counts(plan: Plan) -> collections.Counter:
     return counter
 
 
+def _stage_levels(stage: Stage) -> Iterator[str]:
+    """Every precision level a stage computes or communicates at."""
+    yield stage.level
+    if stage.comm is not None:
+        yield stage.comm
+    for b in stage.body:
+        yield from _stage_levels(b)
+
+
+def _check_levels(stages: Sequence[Stage], spec: BackendSpec) -> None:
+    """A stage at a level off the backend's ladder (a "d" stage on a TPU:
+    no f64 FFT, no f64 Pallas) is refused before lowering, never computed
+    at a lower precision."""
+    ladder = spec.ladder()
+    bad = [s.kind for s in stages
+           if any(lvl not in ladder for lvl in _stage_levels(s))]
+    if bad:
+        raise UnsupportedOnBackend(
+            f"backend {spec.fingerprint()!r} has no f64 datapath: it runs "
+            f"the levels {'/'.join(ladder)}, but the plan runs "
+            f"{', '.join(bad)} off that ladder (e.g. use "
+            f"PrecisionConfig.from_string('sssss'))")
+
+
 def run_stages(stages: Sequence[Stage], x, operands: Mapping, *, N_t: int,
                opts, S: int = 1):
     """Fold ``x`` through ``stages`` (no layout promotion — see run_plan).
@@ -635,6 +660,7 @@ def run_stages(stages: Sequence[Stage], x, operands: Mapping, *, N_t: int,
     here, at lowering time) or an already-resolved :class:`ResolvedOpts`.
     """
     opts = _resolved(opts)
+    _check_levels(stages, opts.spec)
     for stage in stages:
         for counter in _active_counters:
             counter[stage.kind] += 1

@@ -23,6 +23,7 @@ measurement.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Optional
 
@@ -145,16 +146,17 @@ class TimingHarness:
             key = (variant, id(op))
             fn = self._jitted.get(key)
             if fn is None:
-                target = (op.gram(space="parameter").apply
-                          if variant == "gram" else getattr(op, variant))
-
-                def counted(x, _target=target):
+                def counted(op, x):
                     self.n_traces += 1
-                    return _target(x)
+                    if variant == "gram":
+                        return op.gram(space="parameter").apply(x)
+                    return getattr(op, variant)(x)
 
-                fn = jax.jit(counted)
-                # bound-method closures pin the operator's sharded arrays;
-                # cap how many a long-lived harness retains (FIFO evict)
+                # the operator goes in as an argument (its planes are
+                # program inputs, not constants); the partial still pins
+                # its sharded arrays, so cap how many a long-lived harness
+                # retains (FIFO evict)
+                fn = functools.partial(jax.jit(counted), op)
                 mesh_keys = [k for k in self._jitted
                              if isinstance(k, tuple) and len(k) == 2]
                 if len(mesh_keys) >= self.MAX_MESH_ENTRIES:

@@ -26,6 +26,8 @@ are the paper's "purely memory" intermediate phases.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
@@ -69,22 +71,66 @@ def dense_rmatvec(F_col: jax.Array, d_soti: jax.Array) -> jax.Array:
     return jnp.stack(out, axis=0).T  # (N_m, N_t)
 
 
-def fourier_block_column(F_col: jax.Array, dtype=None) -> tuple[jax.Array, jax.Array]:
+# Elements of F_col per set-up chunk (128 MiB of f32): bounds the live
+# padded chunk and its complex transform, whatever N_m is.
+SETUP_CHUNK_ELEMS = 1 << 25
+
+
+def fourier_block_column(F_col: jax.Array, dtype=None, *,
+                         compute_dtype=None) -> tuple[jax.Array, jax.Array]:
     """Phase-0 setup: batched FFT of the zero-padded first block column.
 
-    Always computed at the highest available precision (the paper computes
-    setup in FP64; on CPU with x64 enabled that is reproduced exactly).
+    Computed at ``compute_dtype`` — by default the highest available
+    precision: f64 with x64 on (the paper computes setup in FP64; on CPU
+    that is reproduced exactly), f32 otherwise.  A backend without f64
+    (a TPU) passes f32.
+
+    The transform streams over chunks of N_m columns
+    (:data:`SETUP_CHUNK_ELEMS` elements of F_col per chunk), each written
+    straight into the stored planes: neither the whole
+    padded column nor the whole complex F_hat is ever live, so the set-up
+    peak is F_col plus the two output planes plus one chunk.  Columns are
+    independent, so chunking does not change the result.
 
     Returns TOSI-layout split planes ``(F_hat_re, F_hat_im)`` each of shape
-    (N_t + 1, N_d, N_m) — rfft of length 2*N_t keeps N_t+1 bins.
+    (N_t + 1, N_d, N_m) in ``dtype`` (default: the compute dtype) — rfft
+    of length 2*N_t keeps N_t+1 bins.
     """
     N_t, N_d, N_m = F_col.shape
-    compute = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    col = F_col.astype(compute)
-    padded = jnp.concatenate([col, jnp.zeros_like(col)], axis=0)  # (2Nt, Nd, Nm)
-    F_hat = jnp.fft.rfft(padded, axis=0)  # (Nt+1, Nd, Nm) complex
-    out_dtype = dtype if dtype is not None else compute
-    return F_hat.real.astype(out_dtype), F_hat.imag.astype(out_dtype)
+    if compute_dtype is None:
+        compute_dtype = jnp.float64 if jax.config.jax_enable_x64 \
+            else jnp.float32
+    compute = jax.dtypes.canonicalize_dtype(compute_dtype)
+    out = jax.dtypes.canonicalize_dtype(
+        compute if dtype is None else dtype)
+    chunk = max(1, min(N_m, SETUP_CHUNK_ELEMS // (N_t * N_d)))
+    return _fourier_planes(F_col, compute, out, chunk)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _fourier_planes(F_col, compute, out, chunk: int):
+    N_t, N_d, N_m = F_col.shape
+
+    def planes(cols):
+        F_hat = jnp.fft.rfft(cols.astype(compute), n=2 * N_t, axis=0)
+        return F_hat.real.astype(out), F_hat.imag.astype(out)
+
+    def put(acc, start, cols):
+        return tuple(jax.lax.dynamic_update_slice_in_dim(a, p, start, axis=2)
+                     for a, p in zip(acc, planes(cols)))
+
+    def body(i, acc):
+        start = i * chunk
+        return put(acc, start,
+                   jax.lax.dynamic_slice_in_dim(F_col, start, chunk, axis=2))
+
+    shape = (N_t + 1, N_d, N_m)
+    acc = (jnp.zeros(shape, out), jnp.zeros(shape, out))
+    n_full, tail = divmod(N_m, chunk)
+    acc = jax.lax.fori_loop(0, n_full, body, acc)
+    if tail:
+        acc = put(acc, n_full * chunk, F_col[:, :, n_full * chunk:])
+    return acc
 
 
 # ---------------------------------------------------------------------------

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import compiler_params
-
 F32 = jnp.float32
 NEG_INF = -1e30
 
@@ -93,7 +91,7 @@ def flash_attention_bh(q, k, v, *, causal: bool = True, q_block: int = 256,
             pltpu.VMEM((q_block, 1), F32),         # running max
             pltpu.VMEM((q_block, 1), F32),         # running denominator
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

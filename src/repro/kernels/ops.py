@@ -29,6 +29,7 @@ spelling is ``backend="cpu-interpret"`` +
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -43,6 +44,9 @@ from .padding import pad_planes, pad_to_multiple, round_up
 
 # Back-compat alias: the transition point now lives in DispatchTable.
 SHORT_WIDE_RATIO = DEFAULT_SHORT_WIDE_RATIO
+
+# f32 contractions run at full f32: a TPU's default is one bf16 MXU pass
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def resolve_backend_dispatch(backend=None, dispatch=None):
@@ -76,12 +80,16 @@ def _sbgemv_xla_fused(A_re, A_im, x_re, x_im, mode: str):
     acc = jnp.float64 if A_re.dtype == jnp.float64 else jnp.float32
     if mode == "N":
         X = jnp.stack([x_re, x_im], axis=1)               # (B, 2, n)
-        R = jnp.einsum("bmn,bkn->bkm", A_re, X, preferred_element_type=acc)
-        I = jnp.einsum("bmn,bkn->bkm", A_im, X, preferred_element_type=acc)
+        R = jnp.einsum("bmn,bkn->bkm", A_re, X, preferred_element_type=acc,
+                       precision=_HIGHEST)
+        I = jnp.einsum("bmn,bkn->bkm", A_im, X, preferred_element_type=acc,
+                       precision=_HIGHEST)
         return R[:, 0] - I[:, 1], R[:, 1] + I[:, 0]
     X = jnp.stack([x_re, x_im], axis=1)                   # (B, 2, m)
-    R = jnp.einsum("bmn,bkm->bkn", A_re, X, preferred_element_type=acc)
-    I = jnp.einsum("bmn,bkm->bkn", A_im, X, preferred_element_type=acc)
+    R = jnp.einsum("bmn,bkm->bkn", A_re, X, preferred_element_type=acc,
+                   precision=_HIGHEST)
+    I = jnp.einsum("bmn,bkm->bkn", A_im, X, preferred_element_type=acc,
+                   precision=_HIGHEST)
     if mode == "H":   # conj(A)^T x
         return R[:, 0] + I[:, 1], R[:, 1] - I[:, 0]
     return R[:, 0] - I[:, 1], R[:, 1] + I[:, 0]           # "T"
@@ -113,23 +121,17 @@ def sbgemv(A_re, A_im, x_re, x_im, mode: str = "N", *, out_dtype=None,
         y_re, y_im = fn(A_re, A_im, x_re, x_im, mode)
         return y_re.astype(out_dtype), y_im.astype(out_dtype)
 
+    # A planes go in as stored (the kernels take any m, n): padding them
+    # would copy the whole operator on every call
     bn = min(block_n or spec.default_block_n, max(spec.lane, n))
     itp = spec.pallas_interpret
-    # pad m to sublane multiples, n to a tile multiple (zero rows/cols
-    # contribute zero to the dots)
-    (Ar, Ai), _ = pad_planes((A_re, A_im), 1, spec.sublane)
-    (Ar, Ai), n0 = pad_planes((Ar, Ai), 2, bn)
     if mode == "N":
-        (xr, xi), _ = pad_planes((x_re, x_im), 1, bn)
-        y_re, y_im = _sbgemv.sbgemv_n_complex(Ar, Ai, xr, xi, block_n=bn,
-                                              interpret=itp)
-        y_re, y_im = y_re[:, :m], y_im[:, :m]
+        y_re, y_im = _sbgemv.sbgemv_n_complex(A_re, A_im, x_re, x_im,
+                                              block_n=bn, interpret=itp)
     else:
-        (xr, xi), _ = pad_planes((x_re, x_im), 1, spec.sublane)
-        y_re, y_im = _sbgemv.sbgemv_th_complex(Ar, Ai, xr, xi,
+        y_re, y_im = _sbgemv.sbgemv_th_complex(A_re, A_im, x_re, x_im,
                                                conj=(mode == "H"),
                                                block_n=bn, interpret=itp)
-        y_re, y_im = y_re[:, :n0], y_im[:, :n0]
     return y_re.astype(out_dtype), y_im.astype(out_dtype)
 
 
@@ -151,15 +153,8 @@ def sbgemv_real(A, x, mode: str = "N", *, out_dtype=None,
 
     bn = min(block_n or spec.default_block_n, max(spec.lane, n))
     itp = spec.pallas_interpret
-    A2, _ = pad_to_multiple(A, 1, spec.sublane)
-    A2, n0 = pad_to_multiple(A2, 2, bn)
-    if mode == "N":
-        x2, _ = pad_to_multiple(x, 1, bn)
-        y = _sbgemv.sbgemv_n_real(A2, x2, block_n=bn, interpret=itp)[:, :m]
-    else:
-        x2, _ = pad_to_multiple(x, 1, spec.sublane)
-        y = _sbgemv.sbgemv_th_real(A2, x2, block_n=bn, interpret=itp)[:, :n0]
-    return y.astype(out_dtype)
+    fn = _sbgemv.sbgemv_n_real if mode == "N" else _sbgemv.sbgemv_th_real
+    return fn(A, x, block_n=bn, interpret=itp).astype(out_dtype)
 
 
 def pad_cast(x, pad_to: int, out_dtype, *, backend=None, dispatch=None,
@@ -227,8 +222,9 @@ def _quantize_planes_elementwise(tile_map, *planes):
 def _tile_lvl_per_block(tile_map, B: int, n: int, bn: int):
     """Per-(batch, kernel-tile) int32 level array for the tiled kernels,
     or None when the ``bn``-column kernel grid does not align with the
-    map's cells.  Padded columns (n -> round_up(n, bn)) inherit the last
-    logical column's level — they are zeros, so any level is exact."""
+    map's cells.  Columns past n in the last, partial kernel tile
+    (n -> round_up(n, bn)) inherit the last logical column's level: the
+    kernels mask or drop them, so any level is exact."""
     idx = _ref.expand_tile_levels(tile_map, B, n)          # (B, n) int32
     n_pad = round_up(n, bn)
     if n_pad > n:
@@ -252,11 +248,15 @@ def _sbgemm_xla_fused(A_re, A_im, X_re, X_im, mode: str):
     acc = jnp.float64 if A_re.dtype == jnp.float64 else jnp.float32
     X = jnp.stack([X_re, X_im], axis=1)               # (B, 2, n|m, S)
     if mode == "N":
-        R = jnp.einsum("bmn,bkns->bkms", A_re, X, preferred_element_type=acc)
-        I = jnp.einsum("bmn,bkns->bkms", A_im, X, preferred_element_type=acc)
+        R = jnp.einsum("bmn,bkns->bkms", A_re, X, preferred_element_type=acc,
+                       precision=_HIGHEST)
+        I = jnp.einsum("bmn,bkns->bkms", A_im, X, preferred_element_type=acc,
+                       precision=_HIGHEST)
         return R[:, 0] - I[:, 1], R[:, 1] + I[:, 0]
-    R = jnp.einsum("bmn,bkms->bkns", A_re, X, preferred_element_type=acc)
-    I = jnp.einsum("bmn,bkms->bkns", A_im, X, preferred_element_type=acc)
+    R = jnp.einsum("bmn,bkms->bkns", A_re, X, preferred_element_type=acc,
+                   precision=_HIGHEST)
+    I = jnp.einsum("bmn,bkms->bkns", A_im, X, preferred_element_type=acc,
+                   precision=_HIGHEST)
     if mode == "H":   # conj(A)^T X
         return R[:, 0] + I[:, 1], R[:, 1] - I[:, 0]
     return R[:, 0] - I[:, 1], R[:, 1] + I[:, 0]       # "T"
@@ -304,31 +304,26 @@ def sbgemm(A_re, A_im, X_re, X_im, mode: str = "N", *, out_dtype=None,
         lvl = _tile_lvl_per_block(tile_map, B, n, bn)
         if lvl is None:     # cells cut through kernel tiles: pre-quantize
             A_re, A_im = _quantize_planes_elementwise(tile_map, A_re, A_im)
-    (Ar, Ai), _ = pad_planes((A_re, A_im), 1, spec.sublane)
-    (Ar, Ai), n0 = pad_planes((Ar, Ai), 2, bn)
+    # A planes as stored; only the (small) RHS axis pads to whole tiles
+    (Xr, Xi), _ = pad_planes((X_re, X_im), 2, bs)
     if mode == "N":
-        (Xr, Xi), _ = pad_planes((X_re, X_im), 1, bn)
-        (Xr, Xi), _ = pad_planes((Xr, Xi), 2, bs)
         if lvl is not None:
             Y_re, Y_im = _sbgemv.sbgemm_n_complex_tiled(
-                Ar, Ai, Xr, Xi, lvl, block_n=bn, block_s=bs, interpret=itp)
+                A_re, A_im, Xr, Xi, lvl, block_n=bn, block_s=bs,
+                interpret=itp)
         else:
-            Y_re, Y_im = _sbgemv.sbgemm_n_complex(Ar, Ai, Xr, Xi, block_n=bn,
-                                                  block_s=bs, interpret=itp)
-        Y_re, Y_im = Y_re[:, :m, :S], Y_im[:, :m, :S]
+            Y_re, Y_im = _sbgemv.sbgemm_n_complex(
+                A_re, A_im, Xr, Xi, block_n=bn, block_s=bs, interpret=itp)
     else:
-        (Xr, Xi), _ = pad_planes((X_re, X_im), 1, spec.sublane)
-        (Xr, Xi), _ = pad_planes((Xr, Xi), 2, bs)
         if lvl is not None:
             Y_re, Y_im = _sbgemv.sbgemm_th_complex_tiled(
-                Ar, Ai, Xr, Xi, lvl, conj=(mode == "H"),
+                A_re, A_im, Xr, Xi, lvl, conj=(mode == "H"),
                 block_n=bn, block_s=bs, interpret=itp)
         else:
-            Y_re, Y_im = _sbgemv.sbgemm_th_complex(Ar, Ai, Xr, Xi,
-                                                   conj=(mode == "H"),
-                                                   block_n=bn, block_s=bs,
-                                                   interpret=itp)
-        Y_re, Y_im = Y_re[:, :n0, :S], Y_im[:, :n0, :S]
+            Y_re, Y_im = _sbgemv.sbgemm_th_complex(
+                A_re, A_im, Xr, Xi, conj=(mode == "H"), block_n=bn,
+                block_s=bs, interpret=itp)
+    Y_re, Y_im = Y_re[..., :S], Y_im[..., :S]
     return Y_re.astype(out_dtype), Y_im.astype(out_dtype)
 
 
@@ -353,24 +348,28 @@ def sbgemm_gram(A_re, A_im, *, space: str = "parameter", out_dtype=None,
     out_dtype = out_dtype or A_re.dtype
     spec, table = resolve_backend_dispatch(backend, dispatch)
     _check_tile_support(spec, tile_map)
+    if space not in ("parameter", "data"):
+        raise ValueError(f"bad gram space {space!r}")
     if space == "data":
-        # A A^H == (A^H)^H (A^H): reuse the parameter kernel on the
-        # conjugate-transposed planes.  Tile quantization happens first,
-        # on the original operand grid (it commutes with negation).
+        # the kernel path runs A A^H as (A^H)^H (A^H) on conjugate-
+        # transposed planes; its (n x n) blocks are the (m x m) ones here.
+        # Tile quantization happens first, on the original operand grid
+        # (it commutes with negation).
         if tile_map is not None:
             A_re, A_im = _quantize_planes_elementwise(tile_map, A_re, A_im)
             tile_map = None
-        A_re = A_re.transpose(0, 2, 1)
-        A_im = -A_im.transpose(0, 2, 1)
         m, n = n, m
-    elif space != "parameter":
-        raise ValueError(f"bad gram space {space!r}")
     path = table.gemv_path(m, n, "H", A_re.dtype, spec)
     if path != "pallas":
+        # the oracle contracts in the requested space directly, so the
+        # reference lowering is the oracle bit for bit in both spaces
         if tile_map is not None:
             A_re, A_im = _quantize_planes_elementwise(tile_map, A_re, A_im)
-        G_re, G_im = _ref.sbgemm_gram_ref(A_re, A_im, "parameter")
+        G_re, G_im = _ref.sbgemm_gram_ref(A_re, A_im, space)
     else:
+        if space == "data":
+            A_re = A_re.transpose(0, 2, 1)
+            A_im = -A_im.transpose(0, 2, 1)
         bn = min(block_n or spec.default_block_n, max(spec.lane, n))
         lvl = None
         if tile_map is not None:
@@ -378,15 +377,12 @@ def sbgemm_gram(A_re, A_im, *, space: str = "parameter", out_dtype=None,
             if lvl is None:
                 A_re, A_im = _quantize_planes_elementwise(tile_map,
                                                           A_re, A_im)
-        (Ar, Ai), _ = pad_planes((A_re, A_im), 1, spec.sublane)
-        (Ar, Ai), _ = pad_planes((Ar, Ai), 2, bn)
         if lvl is not None:
             G_re, G_im = _sbgemv.sbgemm_gram_tiled(
-                Ar, Ai, lvl, block_n=bn, interpret=spec.pallas_interpret)
+                A_re, A_im, lvl, block_n=bn, interpret=spec.pallas_interpret)
         else:
             G_re, G_im = _sbgemv.sbgemm_gram_complex(
-                Ar, Ai, block_n=bn, interpret=spec.pallas_interpret)
-        G_re, G_im = G_re[:, :n, :n], G_im[:, :n, :n]
+                A_re, A_im, block_n=bn, interpret=spec.pallas_interpret)
     # enforce exact Hermitian symmetry (kills accumulation-order roundoff)
     G_re = 0.5 * (G_re + G_re.transpose(0, 2, 1))
     G_im = 0.5 * (G_im - G_im.transpose(0, 2, 1))
@@ -417,26 +413,19 @@ def sbgemm_real(A, X, mode: str = "N", *, out_dtype=None,
         lvl = _tile_lvl_per_block(tile_map, B, n, bn)
         if lvl is None:
             (A,) = _quantize_planes_elementwise(tile_map, A)
-    A2, _ = pad_to_multiple(A, 1, spec.sublane)
-    A2, n0 = pad_to_multiple(A2, 2, bn)
+    X2, _ = pad_to_multiple(X, 2, bs)
     if mode == "N":
-        X2, _ = pad_to_multiple(X, 1, bn)
-        X2, _ = pad_to_multiple(X2, 2, bs)
         if lvl is not None:
-            Y = _sbgemv.sbgemm_n_real_tiled(A2, X2, lvl, block_n=bn,
+            Y = _sbgemv.sbgemm_n_real_tiled(A, X2, lvl, block_n=bn,
                                             block_s=bs, interpret=itp)
         else:
-            Y = _sbgemv.sbgemm_n_real(A2, X2, block_n=bn, block_s=bs,
+            Y = _sbgemv.sbgemm_n_real(A, X2, block_n=bn, block_s=bs,
                                       interpret=itp)
-        Y = Y[:, :m, :S]
     else:
-        X2, _ = pad_to_multiple(X, 1, spec.sublane)
-        X2, _ = pad_to_multiple(X2, 2, bs)
         if lvl is not None:
-            Y = _sbgemv.sbgemm_th_real_tiled(A2, X2, lvl, block_n=bn,
+            Y = _sbgemv.sbgemm_th_real_tiled(A, X2, lvl, block_n=bn,
                                              block_s=bs, interpret=itp)
         else:
-            Y = _sbgemv.sbgemm_th_real(A2, X2, block_n=bn, block_s=bs,
+            Y = _sbgemv.sbgemm_th_real(A, X2, block_n=bn, block_s=bs,
                                        interpret=itp)
-        Y = Y[:, :n0, :S]
-    return Y.astype(out_dtype)
+    return Y[..., :S].astype(out_dtype)

@@ -14,8 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ._compat import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _pad_cast_kernel(T: int, x_ref, o_ref):
@@ -37,27 +36,32 @@ def pad_cast(x, pad_to: int, out_dtype, *, block_rows: int = 8,
         in_specs=[pl.BlockSpec((block_rows, T), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, pad_to), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, pad_to), out_dtype),
-        compiler_params=compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)
 
 
-def _unpad_cast_kernel(x_ref, o_ref):
-    o_ref[...] = x_ref[...].astype(o_ref.dtype)
+def _unpad_cast_kernel(keep: int, x_ref, o_ref):
+    o_ref[...] = x_ref[:, :keep].astype(o_ref.dtype)
 
 
 def unpad_cast(x, keep: int, out_dtype, *, block_rows: int = 8,
                interpret: bool = False):
-    """(R, P) -> (R, keep): slice the leading minor-axis entries + cast."""
+    """(R, P) -> (R, keep): slice the leading minor-axis entries + cast.
+
+    The input block spans ``keep`` rounded up to whole 128-lane tiles (or
+    all of P): a block must be lane-aligned or the full minor dim, and
+    ``keep = N_t`` rarely is a multiple of 128."""
     R, P = x.shape
     assert R % block_rows == 0 and keep <= P
     grid = (R // block_rows,)
+    read = min(-(-keep // 128) * 128, P)
     return pl.pallas_call(
-        _unpad_cast_kernel,
+        functools.partial(_unpad_cast_kernel, keep),
         grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, keep), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((block_rows, read), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, keep), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, keep), out_dtype),
-        compiler_params=compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)

@@ -7,9 +7,10 @@ from the :class:`repro.backend.BackendSpec` the op was dispatched on, so
 a backend with different tiling (bf16's 16-row sublanes, a future GPU
 lowering) changes the padding in exactly one place.
 
-Zero padding is semantically free for every op in this package: padded
-rows/columns contribute zeros to the dots and are sliced away by the
-wrapper before returning.
+Zero padding is semantically free where it is used: the stacked rows of
+the pad-cast kernels and the RHS axis of the SBGEMM kernels, sliced away
+by the wrapper before returning.  The operator planes are never padded
+(``kernels/sbgemv.py`` takes them as stored).
 """
 
 from __future__ import annotations
@@ -43,9 +44,3 @@ def pad_planes(planes, axis: int, multiple: int):
         q, _ = pad_to_multiple(p, axis, multiple)
         out.append(q)
     return tuple(out), size
-
-
-def sublane_pad(planes, axis: int, spec):
-    """Pad to the backend's sublane alignment (the short m axis / the
-    stacked-rows axis of the pad-cast kernels)."""
-    return pad_planes(planes, axis, spec.sublane)

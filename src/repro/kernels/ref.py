@@ -6,10 +6,17 @@ mode on CPU, sweeping shapes and dtypes in tests/test_kernels_*).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 _TILE_LEVEL_INDEX = {"h": 0, "s": 1, "d": 2}
+
+
+def _einsum(spec, *operands):
+    """``jnp.einsum`` at the full precision of its operands: a TPU's
+    default for f32 is one bf16 MXU pass."""
+    return jnp.einsum(spec, *operands, precision=jax.lax.Precision.HIGHEST)
 
 
 def sbgemv_real_ref(A, x, mode: str = "N"):
@@ -20,9 +27,9 @@ def sbgemv_real_ref(A, x, mode: str = "N"):
     """
     acc = jnp.float64 if A.dtype == jnp.float64 else jnp.float32
     if mode == "N":
-        y = jnp.einsum("bmn,bn->bm", A.astype(acc), x.astype(acc))
+        y = _einsum("bmn,bn->bm", A.astype(acc), x.astype(acc))
     elif mode == "T":
-        y = jnp.einsum("bmn,bm->bn", A.astype(acc), x.astype(acc))
+        y = _einsum("bmn,bm->bn", A.astype(acc), x.astype(acc))
     else:
         raise ValueError(f"bad mode {mode!r}")
     return y.astype(A.dtype)
@@ -38,14 +45,14 @@ def sbgemv_complex_ref(A_re, A_im, x_re, x_im, mode: str = "N"):
     Ar, Ai = A_re.astype(acc), A_im.astype(acc)
     xr, xi = x_re.astype(acc), x_im.astype(acc)
     if mode == "N":
-        y_re = jnp.einsum("bmn,bn->bm", Ar, xr) - jnp.einsum("bmn,bn->bm", Ai, xi)
-        y_im = jnp.einsum("bmn,bn->bm", Ar, xi) + jnp.einsum("bmn,bn->bm", Ai, xr)
+        y_re = _einsum("bmn,bn->bm", Ar, xr) - _einsum("bmn,bn->bm", Ai, xi)
+        y_im = _einsum("bmn,bn->bm", Ar, xi) + _einsum("bmn,bn->bm", Ai, xr)
     elif mode == "T":
-        y_re = jnp.einsum("bmn,bm->bn", Ar, xr) - jnp.einsum("bmn,bm->bn", Ai, xi)
-        y_im = jnp.einsum("bmn,bm->bn", Ar, xi) + jnp.einsum("bmn,bm->bn", Ai, xr)
+        y_re = _einsum("bmn,bm->bn", Ar, xr) - _einsum("bmn,bm->bn", Ai, xi)
+        y_im = _einsum("bmn,bm->bn", Ar, xi) + _einsum("bmn,bm->bn", Ai, xr)
     elif mode == "H":  # conj(A)^T x
-        y_re = jnp.einsum("bmn,bm->bn", Ar, xr) + jnp.einsum("bmn,bm->bn", Ai, xi)
-        y_im = jnp.einsum("bmn,bm->bn", Ar, xi) - jnp.einsum("bmn,bm->bn", Ai, xr)
+        y_re = _einsum("bmn,bm->bn", Ar, xr) + _einsum("bmn,bm->bn", Ai, xi)
+        y_im = _einsum("bmn,bm->bn", Ar, xi) - _einsum("bmn,bm->bn", Ai, xr)
     else:
         raise ValueError(f"bad mode {mode!r}")
     return y_re.astype(A_re.dtype), y_im.astype(A_re.dtype)
@@ -73,9 +80,9 @@ def sbgemm_real_ref(A, X, mode: str = "N"):
     """
     acc = jnp.float64 if A.dtype == jnp.float64 else jnp.float32
     if mode == "N":
-        Y = jnp.einsum("bmn,bns->bms", A.astype(acc), X.astype(acc))
+        Y = _einsum("bmn,bns->bms", A.astype(acc), X.astype(acc))
     elif mode == "T":
-        Y = jnp.einsum("bmn,bms->bns", A.astype(acc), X.astype(acc))
+        Y = _einsum("bmn,bms->bns", A.astype(acc), X.astype(acc))
     else:
         raise ValueError(f"bad mode {mode!r}")
     return Y.astype(A.dtype)
@@ -93,12 +100,12 @@ def sbgemm_gram_ref(A_re, A_im, space: str = "parameter"):
     acc = jnp.float64 if A_re.dtype == jnp.float64 else jnp.float32
     Ar, Ai = A_re.astype(acc), A_im.astype(acc)
     if space == "parameter":
-        e = lambda X, Y: jnp.einsum("bmn,bmk->bnk", X, Y)
+        e = lambda X, Y: _einsum("bmn,bmk->bnk", X, Y)
         # (Ar - i Ai)^T (Ar + i Ai)
         G_re = e(Ar, Ar) + e(Ai, Ai)
         G_im = e(Ar, Ai) - e(Ai, Ar)
     elif space == "data":
-        e = lambda X, Y: jnp.einsum("bmn,bkn->bmk", X, Y)
+        e = lambda X, Y: _einsum("bmn,bkn->bmk", X, Y)
         # (Ar + i Ai) (Ar^T - i Ai^T)
         G_re = e(Ar, Ar) + e(Ai, Ai)
         G_im = e(Ai, Ar) - e(Ar, Ai)
@@ -118,9 +125,9 @@ def sbgemm_complex_ref(A_re, A_im, X_re, X_im, mode: str = "N"):
     Ar, Ai = A_re.astype(acc), A_im.astype(acc)
     Xr, Xi = X_re.astype(acc), X_im.astype(acc)
     if mode == "N":
-        e = lambda A, X: jnp.einsum("bmn,bns->bms", A, X)
+        e = lambda A, X: _einsum("bmn,bns->bms", A, X)
     elif mode in ("T", "H"):
-        e = lambda A, X: jnp.einsum("bmn,bms->bns", A, X)
+        e = lambda A, X: _einsum("bmn,bms->bns", A, X)
     else:
         raise ValueError(f"bad mode {mode!r}")
     if mode == "H":  # conj(A)^T X

@@ -1,13 +1,12 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape x
 mesh) cell against 512 placeholder devices and extract memory / cost /
 collective statistics for the roofline analysis.
 
-MUST be executed as its own process (``python -m repro.launch.dryrun``) —
-the XLA_FLAGS line above runs before any jax import and pins the device
-count for the whole process.
+MUST be executed as its own process (``python -m repro.launch.dryrun``):
+the module sets ``XLA_FLAGS`` before any jax import, which pins the device
+count for the whole process, and pins that process to the CPU.  Importing
+it from another module raises, so no program that holds a chip can pick up
+the flag by accident.
 
 Methodology (two compiles per cell):
 
@@ -30,10 +29,18 @@ Usage:
   python -m repro.launch.dryrun --arch fftmatvec --mesh single
 """
 
-import argparse
-import json
-import time
-import traceback
+import os
+
+if __name__ != "__main__":
+    raise ImportError("repro.launch.dryrun runs as its own process: "
+                      "python -m repro.launch.dryrun")
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import time  # noqa: E402
+import traceback  # noqa: E402
 
 import jax
 import jax.numpy as jnp

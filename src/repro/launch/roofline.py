@@ -45,13 +45,9 @@ _DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+)$")
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions: older
-    releases return a single-element list of dicts, newer ones a plain
-    dict.  Every roofline consumer goes through this."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``Compiled.cost_analysis()`` as a plain dict (an empty one when the
+    backend reports nothing).  Every roofline consumer goes through this."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def _shape_bytes(type_str: str) -> int:
@@ -182,13 +178,13 @@ def roofline_terms(cost: dict, coll: CollectiveStats,
     """Three roofline terms against ``spec``'s peaks (default: the TPU
     target spec).  The memory term uses the perfect-fusion floor when
     provided (raw cost-analysis bytes kept as ``memory_raw_s``)."""
-    spec = spec or TARGET_SPEC
+    peak_flops, hbm_bw, link_bw = (spec or TARGET_SPEC).roofline_peaks()
     flops = float(cost.get("flops", 0.0))
     bytes_raw = float(cost.get("bytes accessed", 0.0))
     bytes_mem = float(bytes_floor) if bytes_floor is not None else bytes_raw
-    t_compute = flops / spec.peak_flops
-    t_memory = bytes_mem / spec.hbm_bandwidth
-    t_coll = coll.total_bytes / spec.link_bandwidth
+    t_compute = flops / peak_flops
+    t_memory = bytes_mem / hbm_bw
+    t_coll = coll.total_bytes / link_bw
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll}
     dom = max(terms, key=terms.get)
@@ -196,7 +192,7 @@ def roofline_terms(cost: dict, coll: CollectiveStats,
     terms.update({
         "dominant": dom.replace("_s", ""),
         "step_time_bound_s": bound,
-        "memory_raw_s": bytes_raw / spec.hbm_bandwidth,
+        "memory_raw_s": bytes_raw / hbm_bw,
         "flops_per_device": flops,
         "bytes_per_device": bytes_mem,
         "bytes_raw_per_device": bytes_raw,
@@ -223,6 +219,7 @@ def roofline_fraction(mf: float, bound_s: float, n_devices: int,
                       spec: BackendSpec | None = None) -> float:
     """Achieved fraction of compute roofline: useful FLOPs per second at the
     modeled step time vs peak."""
+    peak_flops = (spec or TARGET_SPEC).roofline_peaks()[0]
     if bound_s <= 0:
         return float("nan")
-    return (mf / n_devices / bound_s) / (spec or TARGET_SPEC).peak_flops
+    return (mf / n_devices / bound_s) / peak_flops

@@ -109,13 +109,16 @@ class SolverPrecision:
 def resolve_precision(precision, tol: float) -> SolverPrecision:
     """Normalize a solver ``precision`` argument: a SolverPrecision passes
     through, ``"auto"`` derives per-leg levels from the solve tolerance
-    (:meth:`SolverPrecision.from_tolerance`), any other string is a
-    3-char config like ``"sds"``."""
+    (:meth:`SolverPrecision.from_tolerance`) over the levels this process
+    has — h/s without x64, where a "d" leg would silently run in f32 —,
+    any other string is a 3-char config like ``"sds"``."""
     if isinstance(precision, SolverPrecision):
         return precision
     if isinstance(precision, str):
         if precision == "auto":
-            return SolverPrecision.from_tolerance(tol)
+            ladder = ("h", "s", "d") if jax.config.jax_enable_x64 \
+                else ("h", "s")
+            return SolverPrecision.from_tolerance(tol, ladder=ladder)
         return SolverPrecision.from_string(precision)
     raise TypeError(f"precision must be SolverPrecision or str, "
                     f"got {type(precision).__name__}")
@@ -142,7 +145,9 @@ def col_dot(a, b, level: str):
     acc = accum_dtype()
     af = a.astype(dt).reshape(-1, a.shape[-1])
     bf = b.astype(dt).reshape(-1, b.shape[-1])
-    return jnp.einsum("is,is->s", af, bf, preferred_element_type=acc)
+    # HIGHEST: a TPU's default for f32 is one bf16 MXU pass
+    return jnp.einsum("is,is->s", af, bf, preferred_element_type=acc,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def col_norm(a, level: str):

@@ -114,7 +114,8 @@ def autotune(op, *, tol: float, v=None, ladder: Sequence[str] | None = None,
     ``op`` should be the *highest-precision* operator (its stored Fourier
     blocks are recast down per candidate; upcasting cannot restore lost
     bits).  ``ladder`` defaults to ("d","s") when the operator is
-    double-based, ("s","h") otherwise.  ``variant`` may also be ``"gram"``:
+    double-based and its backend runs f64, ("s","h") otherwise (always on
+    a TPU).  ``variant`` may also be ``"gram"``:
     the fused parameter-space Gram pipeline (Hessian actions / CGNR's
     F*F), pruned with its own eq.-(6) factors (doubled transform terms,
     squared condition number — see ``core.error_model.phase_factors``).  ``slack`` widens the model-prune
@@ -156,7 +157,9 @@ def autotune(op, *, tol: float, v=None, ladder: Sequence[str] | None = None,
     query for another, while the error model stays schedule-blind.
     """
     if ladder is None:
-        ladder = ("d", "s") if op.precision.highest() == "d" else ("s", "h")
+        full = op.opts.resolve().spec.ladder()        # highest first
+        ladder = full[:2] if op.precision.highest() == full[0] \
+            else full[-2:]
     ladder = tuple(ladder)
     adjoint = variant in _ADJOINT_VARIANTS
     model_variant = variant if variant == "gram" else None

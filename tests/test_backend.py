@@ -71,6 +71,75 @@ def test_resolve_unknown_name_lists_known():
         resolve_backend("definitely-not-a-backend")
 
 
+def test_tpu_peaks_keyed_by_device_kind(monkeypatch):
+    """Roofline peaks come from the device kind JAX reports; a TPU kind
+    with no published entry binds no peaks, never a borrowed table, and
+    its roofline is refused."""
+    from types import SimpleNamespace
+    from repro.backend import TPU_PEAKS, UnknownDevice
+    from repro.launch.roofline import CollectiveStats, roofline_terms
+    monkeypatch.delenv(breg.BACKEND_ENV, raising=False)
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    bound = breg.probe_backend(v5e)
+    assert bound.name == "tpu-pallas" and bound.device_kind == "TPU v5 lite"
+    assert bound.hbm_bandwidth == TPU_PEAKS["TPU v5 lite"]["hbm_bandwidth"]
+    coll = CollectiveStats({}, {}, 0)
+    assert roofline_terms({"flops": 1.0}, coll, spec=bound)["compute_s"] > 0
+    unknown = breg.probe_backend(SimpleNamespace(
+        platform="tpu", device_kind="TPU v0 imaginary"))
+    assert unknown.name == "tpu-pallas"
+    with pytest.raises(UnknownDevice, match="TPU v0 imaginary"):
+        roofline_terms({"flops": 1.0}, coll, spec=unknown)
+    # a platform with no spec is refused, not run on the CPU defaults
+    with pytest.raises(ValueError, match="no backend spec"):
+        breg.probe_backend(SimpleNamespace(platform="metal",
+                                           device_kind="M9"))
+
+
+def test_unknown_tpu_kind_runs_the_operator(monkeypatch):
+    """A TPU kind missing from TPU_PEAKS still builds and applies an
+    operator (the Pallas kernels in interpret mode here); only its
+    roofline share is refused."""
+    from types import SimpleNamespace
+    from repro.backend import UnknownDevice
+    from repro.launch.roofline import roofline_fraction
+    monkeypatch.delenv(breg.BACKEND_ENV, raising=False)
+    unknown = breg.probe_backend(SimpleNamespace(
+        platform="tpu", device_kind="TPU v0 imaginary"))
+    spec = dataclasses.replace(unknown, pallas_interpret=True)
+    N_t, N_d, N_m = 6, 3, 20
+    F_col = random_block_column(jax.random.PRNGKey(3), N_t, N_d, N_m)
+    x = jax.random.normal(jax.random.PRNGKey(4), (N_m, N_t))
+    op = FFTMatvec.from_block_column(
+        F_col, precision=PrecisionConfig.from_string("sssss"), backend=spec)
+    assert op.F_hat_re.dtype == F32        # set-up at the top of s/h
+    assert rel_l2(op.matvec(x), dense_matvec(F_col, x)) < 1e-5
+    with pytest.raises(UnknownDevice):
+        roofline_fraction(1.0, 1.0, 1, spec=spec)
+
+
+def test_use_compile_cache_respects_env(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, where set, places the cache and the
+    helper changes nothing; otherwise the cache sits at one fixed path in
+    the checkout."""
+    import os
+    from repro.jax_compat import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = use_compile_cache()
+        assert path == use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert os.path.basename(path) == ".jax_cache"
+        assert os.path.isdir(os.path.join(os.path.dirname(path), "src",
+                                          "repro"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_use_backend_scopes_override_and_custom_registration():
     custom = register_backend(dataclasses.replace(
         CPU_XLA, name="test-custom", sublane=16))

@@ -28,6 +28,19 @@ def _run(code: str) -> dict:
     return json.loads(out.stdout.splitlines()[-1])
 
 
+def test_forced_host_devices_env_pins_cpu_and_extends():
+    """The child simulates its devices on the CPU (on a chip machine the
+    parent holds the chip), and inherited flags and paths survive."""
+    env = forced_host_devices_env(
+        4, {"XLA_FLAGS": "--xla_foo=1", "PYTHONPATH": "/elsewhere",
+            "JAX_PLATFORMS": "tpu"})
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert env["XLA_FLAGS"] == ("--xla_foo=1 "
+                                "--xla_force_host_platform_device_count=4")
+    assert env["PYTHONPATH"].endswith("/elsewhere")
+    assert env["PYTHONPATH"].split(":")[0].endswith("src")
+
+
 def test_fftmatvec_2d_grid_subprocess():
     res = _run(r"""
 import jax, json

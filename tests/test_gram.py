@@ -169,7 +169,9 @@ def test_circulant_gram_halves_fft_and_reorder_stages():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("space", ["parameter", "data"])
-@pytest.mark.parametrize("B,m,n", [(3, 4, 16), (1, 2, 40), (2, 8, 8)])
+@pytest.mark.parametrize("B,m,n", [(3, 4, 16), (1, 2, 40), (2, 8, 8),
+                                   (2, 8, 200),    # partial last tile
+                                   (1, 8, 1100)])  # data space: 3 row tiles
 def test_sbgemm_gram_pallas_matches_oracle(space, B, m, n):
     ks = jax.random.split(jax.random.PRNGKey(10), 2)
     A_re = jax.random.normal(ks[0], (B, m, n), jnp.float32)
@@ -181,6 +183,31 @@ def test_sbgemm_gram_pallas_matches_oracle(space, B, m, n):
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("tiled", [False, True], ids=["plain", "tiled"])
+def test_sbgemm_gram_kernel_row_tiles(tiled, monkeypatch):
+    """The Gram kernels contract over m one row tile at a time (VMEM
+    bounds the tile); a partial last row tile is zeroed past m."""
+    from repro.kernels import sbgemv
+    monkeypatch.setattr(sbgemv, "GRAM_BLOCK_M", 8)
+    B, m, n = 2, 20, 16                   # row tiles of 8: 8, 8, 4
+    ks = jax.random.split(jax.random.PRNGKey(12), 2)
+    A_re = jax.random.normal(ks[0], (B, m, n), jnp.float32)
+    A_im = jax.random.normal(ks[1], (B, m, n), jnp.float32)
+    if tiled:       # every column tile at "h": the bf16 round trip of A
+        lvl = jnp.zeros((B, 1), jnp.int32)
+        got = sbgemv.sbgemm_gram_tiled(A_re, A_im, lvl, block_n=n,
+                                       interpret=True)
+        q = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+        want = ref.sbgemm_gram_ref(q(A_re), q(A_im))
+    else:
+        got = sbgemv.sbgemm_gram_complex(A_re, A_im, block_n=n,
+                                         interpret=True)
+        want = ref.sbgemm_gram_ref(A_re, A_im)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_sbgemm_gram_is_exactly_hermitian():

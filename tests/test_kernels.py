@@ -68,19 +68,68 @@ def test_sbgemv_n_complex(B, m, n, dtype):
 
 
 @pytest.mark.parametrize("B,m,n", [(2, 7, 130), (3, 100, 999)])
-def test_sbgemv_unaligned_shapes(B, m, n):
-    """Wrapper must pad to sublane/lane multiples and slice back."""
-    Ar, Ai, xr, xi = _planes(jax.random.PRNGKey(2), B, m, n, jnp.float32)
-    got = ops.sbgemv(Ar, Ai, xr, xi, "H", block_n=128, **PALLAS)
-    want = ref.sbgemv_complex_ref(Ar, Ai, xr, xi, "H")
-    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
-                               rtol=1e-5, atol=1e-5)
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("mode", ["N", "T", "H"])
+def test_sbgemv_unaligned_shapes(B, m, n, dtype, mode):
+    """A is taken as stored: m is one whole block, the last n tile is
+    partial (masked in the N mode's contraction, dropped on write in the
+    T/H modes), and vectors ride as (B, 1, .) blocks."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    mk = lambda k, shape: jax.random.normal(k, shape, jnp.float32).astype(dtype)
+    xd = n if mode == "N" else m
+    Ar, Ai = mk(ks[0], (B, m, n)), mk(ks[1], (B, m, n))
+    xr, xi = mk(ks[2], (B, xd)), mk(ks[3], (B, xd))
+    got = ops.sbgemv(Ar, Ai, xr, xi, mode, block_n=128, out_dtype=jnp.float32,
+                     **PALLAS)
+    up = lambda a: a.astype(jnp.float32)
+    want = ref.sbgemv_complex_ref(up(Ar), up(Ai), up(xr), up(xi), mode)
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=tol, atol=tol * xd / 64)
 
 
+def _dot_precisions(jaxpr):
+    """The ``precision`` of every dot_general in a jaxpr, kernels included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn.params["precision"]
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if isinstance(sub, jax.extend.core.Jaxpr):
+                yield from _dot_precisions(sub)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("path", ["pallas", "xla", "ref"])
+def test_f32_contractions_run_at_full_precision(path, dtype):
+    """f32 operands contract at HIGHEST: a TPU's default for f32 is one
+    bf16 MXU pass, which turned the "s" rung into a bf16 one on the chip
+    (rel_err 1.9e-3 at the paper shape, vs 4e-7 at HIGHEST).  bf16
+    operands are exact in one pass; the Pallas kernels leave them at the
+    default."""
+    B, m, n = 2, 8, 256
+    A = jnp.zeros((B, m, n), dtype)
+    x = jnp.zeros((B, n), dtype)
+    kw = (PALLAS if path == "pallas"
+          else dict(backend="cpu-xla", dispatch=DispatchTable(force="xla"))
+          if path == "xla" else dict(backend="xla-ref"))
+    jx = jax.make_jaxpr(lambda A, x: ops.sbgemv(A, A, x, x, "N", **kw))(A, x)
+    precs = list(_dot_precisions(jx.jaxpr))
+    assert precs
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    if path == "pallas" and dtype == jnp.bfloat16:
+        assert all(p is None for p in precs), precs
+    else:
+        assert all(p == highest for p in precs), precs
+
+
+@pytest.mark.parametrize("n", [384, 390])      # 390: a partial last tile
 @pytest.mark.parametrize("mode", ["N", "T"])
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_sbgemv_real(mode, dtype):
-    B, m, n = 3, 24, 384
+def test_sbgemv_real(mode, dtype, n):
+    B, m = 3, 24
     k1, k2 = jax.random.split(jax.random.PRNGKey(3))
     A = jax.random.normal(k1, (B, m, n), jnp.float32).astype(dtype)
     x = jax.random.normal(k2, (B, m if mode == "T" else n),
@@ -106,7 +155,10 @@ def test_pad_cast_kernel(R, T, P, din, dout):
                                   np.asarray(want, np.float32))
 
 
-@pytest.mark.parametrize("R,P,keep", [(8, 200, 100), (16, 66, 33)])
+# (8, 2000, 1000): N_t = 1000 is no multiple of 128, so the input block
+# reads 1024 lanes and the kernel keeps the first 1000
+@pytest.mark.parametrize("R,P,keep", [(8, 200, 100), (16, 66, 33),
+                                      (8, 2000, 1000), (16, 300, 130)])
 def test_unpad_cast_kernel(R, P, keep):
     x = jax.random.normal(jax.random.PRNGKey(5), (R, P), jnp.float32)
     got = pal_unpad_cast(x, keep, jnp.bfloat16, interpret=True)

@@ -12,6 +12,8 @@ from repro.backend import DispatchTable
 from repro.core import (ExecOpts, FFTMatvec, PrecisionConfig,
                         dense_from_block_column, dense_matvec, dense_rmatvec,
                         heat_equation_p2o, random_block_column, rel_l2)
+from repro.core import toeplitz
+from repro.core.toeplitz import fourier_block_column
 
 PALLAS_INTERPRET = ExecOpts(backend="cpu-interpret",
                             dispatch=DispatchTable(force="pallas"),
@@ -87,6 +89,68 @@ def test_heat_equation_p2o_is_lti():
     assert rel_l2(op.matvec(m), ref) < 1e-12
     # impulse response decays (diffusion smooths), so kappa is moderate
     assert jnp.linalg.norm(F_col[-1]) <= jnp.linalg.norm(F_col[0]) * 10
+
+
+@pytest.mark.parametrize("chunk", [1, 3, 7, 17])
+def test_chunked_setup_matches_whole_rfft(chunk, monkeypatch):
+    """The set-up streams its FFT over chunks of N_m columns; chunking
+    (with or without a ragged tail) must not change F_hat."""
+    Nt, Nd, Nm = 9, 3, 17
+    monkeypatch.setattr(toeplitz, "SETUP_CHUNK_ELEMS", chunk * Nt * Nd)
+    F_col = random_block_column(jax.random.PRNGKey(11), Nt, Nd, Nm,
+                                dtype=jnp.float64)
+    F = np.asarray(F_col)
+    want = np.fft.rfft(np.concatenate([F, np.zeros_like(F)]), axis=0)
+    F_re, F_im = fourier_block_column(F_col)
+    assert F_re.shape == (Nt + 1, Nd, Nm) and F_re.dtype == jnp.float64
+    np.testing.assert_allclose(np.asarray(F_re), want.real, atol=1e-12)
+    np.testing.assert_allclose(np.asarray(F_im), want.imag, atol=1e-12)
+    # stored at a lower rung: the cast of the same transform
+    s_re, _ = fourier_block_column(F_col, jnp.float32)
+    assert s_re.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(s_re),
+                                  np.asarray(F_re).astype(np.float32))
+
+
+@pytest.mark.parametrize("cfg", ["ddddd", "sssds", "dssdd"])
+def test_d_phase_on_tpu_backend_raises(cfg):
+    """A TPU has no f64 FFT and no f64 Pallas: a plan with a "d" phase is
+    refused on tpu-pallas, never run in f32 in silence.  Its set-up still
+    builds (at f32), and the s/h ladder runs."""
+    from repro.backend import UnsupportedOnBackend
+    Nt, Nd, Nm = 8, 2, 16
+    F_col = random_block_column(jax.random.PRNGKey(12), Nt, Nd, Nm)
+    # XLA lowerings throughout: the Mosaic kernels cannot run on the CPU
+    opts = ExecOpts(backend="tpu-pallas",
+                    dispatch=DispatchTable(force="xla"), fuse_pad_cast=False)
+    op = FFTMatvec.from_block_column(
+        F_col, precision=PrecisionConfig.from_string(cfg), opts=opts)
+    m = jnp.ones((Nm, Nt), jnp.float32)
+    with pytest.raises(UnsupportedOnBackend, match="no f64"):
+        op.matvec(m)
+    ok = op.with_precision(PrecisionConfig.from_string("sssss"))
+    assert rel_l2(ok.matvec(m), dense_matvec(F_col, m)) < 1e-5
+
+
+def test_jitted_takes_operator_planes_as_arguments():
+    """The operator is a pytree of its F_hat planes: its jitted entry
+    points take the planes as program inputs, never as constants baked
+    into the program (GBs at the paper shape)."""
+    Nt, Nd, Nm = 8, 2, 6
+    F_col = random_block_column(jax.random.PRNGKey(13), Nt, Nd, Nm,
+                                dtype=jnp.float64)
+    op = FFTMatvec.from_block_column(F_col)
+    assert jax.tree_util.tree_leaves(op) == [op.F_hat_re, op.F_hat_im]
+    m = jax.random.normal(jax.random.PRNGKey(14), (Nm, Nt), jnp.float64)
+    d = jax.random.normal(jax.random.PRNGKey(15), (Nd, Nt), jnp.float64)
+    mv, rmv = op.jitted()
+    mm, _ = op.jitted_block()
+    for fn, x, want in ((mv, m, op.matvec(m)), (rmv, d, op.rmatvec(d)),
+                        (mm, m, op.matvec(m)),
+                        (op.gram().jitted(), m, op.rmatvec(op.matvec(m)))):
+        lowered = fn.func.lower(*fn.args, x)
+        assert len(jax.tree_util.tree_leaves(lowered.args_info)) == 3
+        assert rel_l2(fn(x), want) < 1e-13
 
 
 def test_io_dtype_follows_highest_level():

@@ -478,6 +478,23 @@ def test_cache_synthetic_timer_never_answers_real_runs(tmp_path):
     assert res1.cache_key.to_string() != res2.cache_key.to_string()
 
 
+def test_autotune_ladder_on_tpu_backend_is_s_h():
+    """A TPU has no f64 datapath: even a double-built operator is tuned
+    over ("s", "h") there, and no candidate runs a "d" phase."""
+    from repro.backend import DispatchTable
+    from repro.core import ExecOpts
+    _, F_col, m = small_problem()
+    # XLA lowerings throughout: the Mosaic kernels cannot run on the CPU
+    opts = ExecOpts(backend="tpu-pallas", dispatch=DispatchTable(force="xla"),
+                    fuse_pad_cast=False)
+    op = FFTMatvec.from_block_column(F_col, opts=opts)
+    assert op.precision.highest() == "d"
+    res = autotune(op, tol=1e-3, v=m.astype(jnp.float32), timer=fake_timer)
+    assert res.records and all("d" not in r.config.to_string()
+                               for r in res.records)
+    assert "d" not in res.config.to_string()
+
+
 # ---------------------------------------------------------------------------
 # Solver integration
 # ---------------------------------------------------------------------------
@@ -508,6 +525,10 @@ def test_resolve_precision_forms():
     assert resolve_precision(p, 1e-8) is p
     assert resolve_precision("sds", 1e-8) == p
     assert resolve_precision("auto", 1e-4).to_string() == "hss"
+    assert resolve_precision("auto", 1e-10).to_string() == "ddd"
+    # without x64 a "d" leg would silently run in f32: auto stays on h/s
+    with jax.enable_x64(False):
+        assert resolve_precision("auto", 1e-10).to_string() == "sss"
     with pytest.raises(TypeError):
         resolve_precision(42, 1e-8)
     with pytest.raises(ValueError):
