@@ -28,7 +28,11 @@ run at the lower of the adjacent compute phases' levels (paper footnote 8).
 Instrumentation: :func:`stage_counts` counts a plan's stages statically and
 :func:`record_stages` counts stages as the executor runs them (trace-time
 under ``jit``) — this is how the fused Gram pipeline's "half the FFT/reorder
-work" claim is verified in the tests rather than asserted.
+work" claim is verified in the tests rather than asserted.  The executor
+runs under ``jax.named_scope("fftmatvec")`` and each stage under
+``jax.named_scope(<kind>)``: HLO metadata only, so every lowered op's
+``op_name`` reads ``.../fftmatvec/<kind>/...`` and a device profile names
+the stage of each op.
 """
 
 from __future__ import annotations
@@ -664,7 +668,8 @@ def run_stages(stages: Sequence[Stage], x, operands: Mapping, *, N_t: int,
     for stage in stages:
         for counter in _active_counters:
             counter[stage.kind] += 1
-        x = _STAGE_IMPLS[stage.kind](stage, x, operands, N_t, S, opts)
+        with jax.named_scope(stage.kind):
+            x = _STAGE_IMPLS[stage.kind](stage, x, operands, N_t, S, opts)
     return x
 
 
@@ -677,13 +682,14 @@ def run_plan(plan: Plan, x, operands: Mapping, *, N_t: int, opts):
     Pallas pad/cast kernels), with Phase 3 dispatching to SBGEMM.
     ``operands`` maps operand tags ("F", "G") to split (re, im) TOSI planes.
     """
-    if x.ndim == 3:
-        R, _, S = x.shape
-        flat = x.transpose(2, 0, 1).reshape(S * R, N_t)
-        y = run_stages(plan, flat, operands, N_t=N_t, opts=opts, S=S)
-        R_out = y.shape[0] // S
-        return y.reshape(S, R_out, N_t).transpose(1, 2, 0)
-    return run_stages(plan, x, operands, N_t=N_t, opts=opts, S=1)
+    with jax.named_scope("fftmatvec"):
+        if x.ndim == 3:
+            R, _, S = x.shape
+            flat = x.transpose(2, 0, 1).reshape(S * R, N_t)
+            y = run_stages(plan, flat, operands, N_t=N_t, opts=opts, S=S)
+            R_out = y.shape[0] // S
+            return y.reshape(S, R_out, N_t).transpose(1, 2, 0)
+        return run_stages(plan, x, operands, N_t=N_t, opts=opts, S=1)
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,7 @@ def pad_cast(x, pad_to: int, out_dtype, *, block_rows: int = 8,
         out_shape=jax.ShapeDtypeStruct((R, pad_to), out_dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="pad_cast",
     )(x)
 
 
@@ -64,4 +65,5 @@ def unpad_cast(x, keep: int, out_dtype, *, block_rows: int = 8,
         out_shape=jax.ShapeDtypeStruct((R, keep), out_dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="unpad_cast",
     )(x)

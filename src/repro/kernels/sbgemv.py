@@ -129,6 +129,7 @@ def sbgemv_th_complex(A_re, A_im, x_re, x_im, *, conj: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="sbgemv_th_complex",
     )(A_re, A_im, x_re[:, None], x_im[:, None])
     return y_re[:, 0], y_im[:, 0]
 
@@ -180,6 +181,7 @@ def sbgemv_n_complex(A_re, A_im, x_re, x_im, *, block_n: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="sbgemv_n_complex",
     )(A_re, A_im, *(_whole_tiles(x, block_n) for x in (x_re, x_im)))
     return y_re[:, 0], y_im[:, 0]
 
@@ -208,6 +210,7 @@ def sbgemv_th_real(A, x, *, block_n: int = 512, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="sbgemv_th_real",
     )(A, x[:, None])[:, 0]
 
 
@@ -236,6 +239,7 @@ def sbgemv_n_real(A, x, *, block_n: int = 512, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="sbgemv_n_real",
     )(A, _whole_tiles(x, block_n))[:, 0]
 
 
@@ -304,6 +308,7 @@ def sbgemm_th_complex(A_re, A_im, X_re, X_im, *, conj: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
+        name="sbgemm_th_complex",
     )(A_re, A_im, X_re, X_im)
 
 
@@ -353,6 +358,7 @@ def sbgemm_n_complex(A_re, A_im, X_re, X_im, *, block_n: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="sbgemm_n_complex",
     )(A_re, A_im, X_re, X_im)
 
 
@@ -428,6 +434,7 @@ def sbgemm_gram_complex(A_re, A_im, *, block_n: int = 512,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="sbgemm_gram_complex",
     )(A_re, A_re, A_im, A_im)
 
 
@@ -457,6 +464,7 @@ def sbgemm_th_real(A, X, *, block_n: int = 512, block_s: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
+        name="sbgemm_th_real",
     )(A, X)
 
 
@@ -487,6 +495,7 @@ def sbgemm_n_real(A, X, *, block_n: int = 512, block_s: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="sbgemm_n_real",
     )(A, X)
 
 
@@ -577,6 +586,7 @@ def sbgemm_th_complex_tiled(A_re, A_im, X_re, X_im, lvl, *, conj: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
+        name="sbgemm_th_complex_tiled",
     )(_lvl_tiles(lvl), A_re, A_im, X_re, X_im)
 
 
@@ -624,6 +634,7 @@ def sbgemm_n_complex_tiled(A_re, A_im, X_re, X_im, lvl, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="sbgemm_n_complex_tiled",
     )(_lvl_tiles(lvl), A_re, A_im, X_re, X_im)
 
 
@@ -672,6 +683,7 @@ def sbgemm_gram_tiled(A_re, A_im, lvl, *, block_n: int = 512,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="sbgemm_gram_tiled",
     )(_lvl_tiles(lvl), _lvl_tiles(lvl), A_re, A_re, A_im, A_im)
 
 
@@ -700,6 +712,7 @@ def sbgemm_th_real_tiled(A, X, lvl, *, block_n: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
+        name="sbgemm_th_real_tiled",
     )(_lvl_tiles(lvl), A, X)
 
 
@@ -733,4 +746,5 @@ def sbgemm_n_real_tiled(A, X, lvl, *, block_n: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="sbgemm_n_real_tiled",
     )(_lvl_tiles(lvl), A, X)

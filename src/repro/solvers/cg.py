@@ -13,13 +13,17 @@ entirely by ``matmat``/``rmatmat``.
 
 The loop is host-driven (paper-style: per-iteration residual recording
 and early exit); each iteration costs one operator application plus
-O(1) reductions.
+O(1) reductions.  A profile shows each ``pcg`` call as a host span
+``pcg.solve`` and each blocking read of a device value in it as
+``pcg.sync`` (``jax.profiler.TraceAnnotation``: nothing is recorded when
+no profiler runs).
 """
 
 from __future__ import annotations
 
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -28,6 +32,14 @@ from .precision import (SolverPrecision, col_dot, col_norm,
 from .result import SolveResult
 
 _SAFE = lambda x: jnp.where(x == 0, 1, x)
+
+
+def _host_norm(v, ortho, k: int):
+    """``col_norm(v)`` read on the host as float64; the ``pcg.sync`` span
+    covers the wait for the device value, not the dispatch."""
+    norm = col_norm(v, ortho)
+    with jax.profiler.TraceAnnotation("pcg.sync", k=k):
+        return np.asarray(norm, np.float64)
 
 
 def pcg(A: Callable, b, *, x0=None, tol=1e-10, maxiter: int = 500,
@@ -64,7 +76,15 @@ def pcg(A: Callable, b, *, x0=None, tol=1e-10, maxiter: int = 500,
     precision = resolve_precision(precision, float(np.min(tol)))
     if multi_rhs is None:
         multi_rhs = b.ndim >= 3
-    squeeze = not multi_rhs
+    S = b.shape[-1] if multi_rhs else 1
+    with jax.profiler.TraceAnnotation("pcg.solve", S=S, maxiter=maxiter):
+        return _pcg(A, b, x0=x0, tol=tol, maxiter=maxiter, M=M,
+                    squeeze=not multi_rhs, col_maxiter=col_maxiter,
+                    precision=precision)
+
+
+def _pcg(A, b, *, x0, tol, maxiter, M, squeeze, col_maxiter,
+         precision) -> SolveResult:
     if squeeze:
         b = b[..., None]
     S = b.shape[-1]
@@ -90,10 +110,10 @@ def pcg(A: Callable, b, *, x0=None, tol=1e-10, maxiter: int = 500,
     z = _user_shaped(M, r).astype(rec_dt) if M is not None else r
     p = z
     rz = col_dot(r, z, ortho)
-    b_norm = np.asarray(col_norm(b, ortho), np.float64)
+    b_norm = _host_norm(b, ortho, 0)
     b_norm = np.where(b_norm == 0, 1.0, b_norm)
 
-    relres = np.asarray(col_norm(r, ortho), np.float64) / b_norm
+    relres = _host_norm(r, ortho, 0) / b_norm
     conv = relres < tol_col              # converged columns (stay frozen)
     frozen = conv | (budget <= 0)        # frozen = converged or out of budget
     col_iters = np.zeros((S,), dtype=int)
@@ -114,7 +134,7 @@ def pcg(A: Callable, b, *, x0=None, tol=1e-10, maxiter: int = 500,
         alpha = jnp.where(active, alpha, 0).astype(rec_dt)
         x = (x + p * alpha).astype(rec_dt)
         r = (r - Ap * alpha).astype(rec_dt)
-        relres_new = np.asarray(col_norm(r, ortho), np.float64) / b_norm
+        relres_new = _host_norm(r, ortho, k) / b_norm
         # frozen columns report the residual they froze at (their r is
         # untouched, but recompute noise must never un-freeze them)
         relres = np.where(frozen, relres, relres_new)

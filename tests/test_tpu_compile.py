@@ -15,6 +15,7 @@ another worker, whose fixture would then skip).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,3 +154,44 @@ def test_paper_matvec_compiles_with_pallas_phase3(one_chip):
     assert kernels, "Phase 3 did not compile to a Pallas kernel"
     plane_bytes = B * N_D * N_M * 4
     assert compiled.memory_analysis().temp_size_in_bytes < plane_bytes // 8
+
+
+@pytest.mark.parametrize("call", ["matvec", "rmatmat", "gram"])
+def test_kernels_and_fusions_carry_stage_scopes(one_chip, call):
+    """Each Pallas kernel is named after its function, and every kernel and
+    fusion of the compiled program (at a small shape) carries the plan
+    executor's ``fftmatvec/<stage kind>`` scope in its ``op_name``, which a
+    device profile shows for each op."""
+    n_t, n_d, n_m = 64, 8, 512
+    cfg = PrecisionConfig.from_string("sssss")
+    opts = ExecOpts(backend="tpu-pallas")
+    F = _arg((n_t + 1, n_d, n_m), jnp.float32, one_chip)
+    x = _arg((n_d if call == "rmatmat" else n_m, n_t), jnp.float32, one_chip)
+
+    def fn(Fr, Fi, x):
+        op = FFTMatvec(Fr, Fi, n_t, cfg, opts)
+        if call == "gram":
+            return op.gram(space="parameter", mode="exact").apply(x)
+        return getattr(op, call)(x)
+
+    text = _compile(fn, F, F, x).as_text()
+    entry = text[text.index("\nENTRY"):].splitlines()
+    kernel = "sbgemv_n_complex" if call == "matvec" else "sbgemv_th_complex"
+    assert any(line.lstrip().startswith(f"%{kernel}.")
+               and f"/fftmatvec/gemv/{kernel}/" in line for line in entry)
+    timed = [line for line in entry
+             if 'custom_call_target="tpu_custom_call"' in line
+             or re.search(r"\sfusion\(", line)]
+    assert timed
+    scoped = re.compile(r'op_name="[^"]*/fftmatvec/[a-z_]+/')
+
+    def body(line):             # a fusion without metadata: what it calls
+        m = re.search(r"calls=%?([\w.\-]+)", line)
+        if not m:
+            return ""
+        start = text.index(f"%{m.group(1)} ")
+        return text[start:text.index("\n}", start)]
+
+    bare = [line.strip()[:100] for line in timed
+            if not scoped.search(line) and not scoped.search(body(line))]
+    assert not bare
