@@ -164,10 +164,16 @@ def phase3_kernels(compiled, n_bins: int) -> int:
                and plane.search(line))
 
 
+def local_dims(op) -> tuple:
+    """One device's (N_d, N_m) share of the operator."""
+    p_r, p_c = op.grid_shape()
+    return op.N_d // p_r, op.N_m // p_c
+
+
 def expected_phase3(op, modes) -> int:
     """How many Phase-3 stages auto dispatch sends to the Pallas kernels."""
     r = op.opts.resolve()
-    _, n_d, n_m = op.F_hat_re.addressable_shards[0].data.shape
+    n_d, n_m = local_dims(op)
     return sum(r.table.gemv_path(n_d, n_m, mode, op.F_hat_re.dtype, r.spec)
                == "pallas" for mode in modes)
 
@@ -360,9 +366,14 @@ def run_mesh(seed: int, devs) -> None:
         jax.block_until_ready((op.F_hat_re, op.F_hat_im))
         del F_col
         t_setup = time.perf_counter() - t0
-        local = (op.F_hat_re.shape[0], N_D // grid[0], N_m // grid[1])
+        # each device's block, tile-padded as the backend stores planes
+        tile = op.opts.resolve().spec.plane_tile or (1, 1)
+        local = (op.F_hat_re.shape[0],
+                 *(-(-n // t) * t for n, t in zip(local_dims(op), tile)))
         shards = op.F_hat_re.addressable_shards
-        check({s.device for s in shards} == set(devs)
+        check((op.N_d, op.N_m) == (N_D, N_m)
+              and local_dims(op) == (N_D // grid[0], N_m // grid[1])
+              and {s.device for s in shards} == set(devs)
               and all(s.data.shape == local for s in shards),
               f"{grid}: F_hat shards {[(s.device.id, s.data.shape) for s in shards]}")
         report(phase="mesh_setup", grid=f"{grid[0]}x{grid[1]}",

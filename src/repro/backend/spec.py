@@ -103,6 +103,18 @@ class BackendSpec:
         (f64 canonicalizes to f32 where x64 is off)."""
         return jnp.float64 if self.f64 else jnp.float32
 
+    @property
+    def plane_tile(self):
+        """``(rows, lanes)`` that set-up pads the F_hat planes' (N_d, N_m)
+        to whole multiples of, or None to store them unpadded.  A compiled
+        Pallas Phase 3 reads its plane operands row-major in (sublane,
+        lane) tiles; tile-padded planes are stored in that layout by the
+        device's default, and every program reads the unpadded planes as a
+        view of them, with no copy (DESIGN.md §12)."""
+        if self.pallas and not self.pallas_interpret:
+            return (self.sublane, self.lane)
+        return None
+
     def roofline_peaks(self) -> tuple[float, float, float]:
         """``(peak_flops, hbm_bandwidth, link_bandwidth)``, or
         :class:`UnknownDevice` for a spec bound to a device whose peaks

@@ -103,6 +103,42 @@ def _auto_mesh(p_shape: Tuple[int, int, int], row_axis, col_axis,
     return Mesh(np.asarray(devs).reshape(p_r, p_c), (row_axis, col_axis))
 
 
+def _setup(spec, precision: PrecisionConfig, mesh=None, row_axis="row",
+           col_axis="col"):
+    """Phase 0 as ``spec``'s operators run it, ``F_col -> (F_hat_re,
+    F_hat_im)``: at the top of the backend's ladder (a TPU has no f64 FFT:
+    there it is f32, whatever x64 says), stored at the gemv level and
+    tile-padded as the backend's Phase 3 reads the planes.  On a mesh, a
+    jitted program in which each device transforms its own (row, col)
+    shard of F_col in place: F_hat is never assembled on one device."""
+    setup = functools.partial(
+        fourier_block_column, dtype=prec.real_dtype(precision.gemv),
+        compute_dtype=spec.setup_dtype, tile=spec.plane_tile)
+    if mesh is None:
+        return setup
+    parts = P(None, _axis_or_none(row_axis), _axis_or_none(col_axis))
+    return jax.jit(shard_map(setup, mesh=mesh, in_specs=parts,
+                             out_specs=(parts, parts)))
+
+
+def stored_planes(F_re, F_im, dims=None, grid=(1, 1)):
+    """The (K, N_d, N_m) planes of stored ones, ``dims`` = (N_d, N_m).
+    Where set-up stored them tile-padded (:attr:`BackendSpec.plane_tile`),
+    each block of the (p_r, p_c) ``grid`` holds its (N_d / p_r, N_m / p_c)
+    part at its leading corner.  On one block, inside a jitted program on
+    the TPU, the slice is a bitcast of the stored buffers, which the
+    Phase-3 kernel reads in place (DESIGN.md §12)."""
+    if dims is None or F_re.shape[1:] == tuple(dims):
+        return F_re, F_im
+    N_d, N_m = dims
+    if grid == (1, 1):
+        return F_re[:, :N_d, :N_m], F_im[:, :N_d, :N_m]
+    (p_r, p_c), (K, D, M) = grid, F_re.shape
+    return tuple(F.reshape(K, p_r, D // p_r, p_c, M // p_c)
+                 [:, :, :N_d // p_r, :, :N_m // p_c].reshape(K, N_d, N_m)
+                 for F in (F_re, F_im))
+
+
 # ---------------------------------------------------------------------------
 # Local (per-shard) pipelines: plan construction + the shared executor.
 # ---------------------------------------------------------------------------
@@ -150,7 +186,7 @@ def _local_gram(F_re, F_im, v, N_t: int, cfg: PrecisionConfig,
     jax.tree_util.register_dataclass,
     data_fields=["F_hat_re", "F_hat_im"],
     meta_fields=["N_t", "precision", "opts", "mesh", "row_axis", "col_axis",
-                 "comm_level", "collective"])
+                 "comm_level", "collective", "dims"])
 @dataclasses.dataclass
 class FFTMatvec:
     """Block-triangular Toeplitz matvec operator.
@@ -169,7 +205,7 @@ class FFTMatvec:
     the mesh paths wrap the same plan (plus Psum stages) in ``shard_map``.
     """
 
-    F_hat_re: jax.Array          # (K, N_d, N_m) TOSI, stored at gemv level
+    F_hat_re: jax.Array          # (K, N_d, N_m) TOSI, maybe tile-padded
     F_hat_im: jax.Array
     N_t: int
     precision: PrecisionConfig = PrecisionConfig()
@@ -179,6 +215,7 @@ class FFTMatvec:
     col_axis: AxisSpec = "col"
     comm_level: Optional[str] = None     # reduction precision (None = reduce)
     collective: Optional[str] = None     # pipeline.COLLECTIVE_KINDS override
+    dims: Optional[Tuple[int, int]] = None   # (N_d, N_m); None: planes' own
 
     # -- constructors -------------------------------------------------------
     @classmethod
@@ -209,22 +246,11 @@ class FFTMatvec:
                 raise ValueError(f"unknown mesh spec {mesh!r}")
             mesh = _auto_mesh(F_col.shape, row_axis, col_axis,
                               devices=devices, grid=grid, net=net)
-        # set-up runs at the top of the backend's ladder (a TPU has no f64
-        # FFT: there it is f32, whatever x64 says)
-        setup = functools.partial(
-            fourier_block_column, dtype=prec.real_dtype(precision.gemv),
-            compute_dtype=resolve_backend(opts.backend).setup_dtype)
-        if mesh is None:
-            F_re, F_im = setup(F_col)
-        else:
-            # each device transforms its own (row, col) shard of F_col in
-            # place: F_hat is never assembled on one device
-            spec = P(None, _axis_or_none(row_axis), _axis_or_none(col_axis))
-            F_re, F_im = jax.jit(shard_map(
-                setup, mesh=mesh, in_specs=spec,
-                out_specs=(spec, spec)))(F_col)
+        F_re, F_im = _setup(resolve_backend(opts.backend), precision, mesh,
+                            row_axis, col_axis)(F_col)
         return cls(F_re, F_im, F_col.shape[0], precision, opts, mesh,
-                   row_axis, col_axis, comm_level, collective)
+                   row_axis, col_axis, comm_level, collective,
+                   tuple(F_col.shape[1:]))
 
     def with_precision(self, precision: PrecisionConfig) -> "FFTMatvec":
         """Same operator retuned to another per-phase config.
@@ -301,11 +327,24 @@ class FFTMatvec:
     # -- shapes --------------------------------------------------------------
     @property
     def N_d(self) -> int:
-        return self.F_hat_re.shape[1]
+        return (self.dims or self.F_hat_re.shape[1:])[0]
 
     @property
     def N_m(self) -> int:
-        return self.F_hat_re.shape[2]
+        return (self.dims or self.F_hat_re.shape[1:])[1]
+
+    @property
+    def planes(self):
+        """The (K, N_d, N_m) F_hat planes (see :func:`stored_planes`)."""
+        return stored_planes(self.F_hat_re, self.F_hat_im, self.dims,
+                             self.grid_shape())
+
+    def local_planes(self, F_re, F_im):
+        """One device's (K, N_d / p_r, N_m / p_c) planes, from its stored
+        block (inside ``shard_map``)."""
+        p_r, p_c = self.grid_shape()
+        dims = self.dims and (self.dims[0] // p_r, self.dims[1] // p_c)
+        return stored_planes(F_re, F_im, dims)
 
     @property
     def io_dtype(self):
@@ -389,9 +428,8 @@ class FFTMatvec:
         opts, N_t, io_dtype = self.opts, self.N_t, self.io_dtype
         plan = self.plan(adjoint=adjoint)
         if self.mesh is None:
-            y = pipeline.run_plan(plan, x, {"F": (self.F_hat_re,
-                                                  self.F_hat_im)},
-                                  N_t=N_t, opts=opts)
+            y = pipeline.run_plan(plan, x, {"F": self.planes}, N_t=N_t,
+                                  opts=opts)
             return y.astype(io_dtype)
 
         row, col = self._row, self._col
@@ -400,7 +438,8 @@ class FFTMatvec:
         in_axis, out_axis = (row, col) if adjoint else (col, row)
 
         def body(F_re, F_im, x_loc):
-            y = pipeline.run_plan(plan, x_loc, {"F": (F_re, F_im)},
+            y = pipeline.run_plan(plan, x_loc,
+                                  {"F": self.local_planes(F_re, F_im)},
                                   N_t=N_t, opts=opts)
             return y.astype(io_dtype)
 
@@ -481,7 +520,7 @@ def phase_callables(op: FFTMatvec, adjoint: bool = False):
     plan into phase groups (the reorders time with the gemv they wrap,
     matching the paper's breakdown)."""
     plan = pipeline.matvec_plan(op.precision, adjoint=adjoint)
-    N_t, opts, io_dtype = op.N_t, op.opts, op.io_dtype
+    N_t, opts, io_dtype, dims = op.N_t, op.opts, op.io_dtype, op.dims
     # group by stage kind (reorders attach to the gemv they wrap), robust
     # to the plan's exact stage order
     group_of = {"pad": "pad", "fft": "fft", "reorder": "gemv",
@@ -491,8 +530,9 @@ def phase_callables(op: FFTMatvec, adjoint: bool = False):
 
     def make(stages, final: bool):
         def f(F_re, F_im, x):      # the planes as arguments, not constants
-            y = pipeline.run_stages(stages, x, {"F": (F_re, F_im)}, N_t=N_t,
-                                    opts=opts)
+            y = pipeline.run_stages(stages, x,
+                                    {"F": stored_planes(F_re, F_im, dims)},
+                                    N_t=N_t, opts=opts)
             return y.astype(io_dtype) if final else y
         return functools.partial(jax.jit(f), op.F_hat_re, op.F_hat_im)
 

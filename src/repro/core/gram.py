@@ -85,7 +85,7 @@ class GramOperator:
             r = op.opts.resolve()
             dt = prec.real_dtype(op.precision.gemv)
             G_re, G_im = kops.sbgemm_gram(
-                op.F_hat_re, op.F_hat_im, space=space, out_dtype=dt,
+                *op.planes, space=space, out_dtype=dt,
                 backend=r.spec, dispatch=r.table.for_dtype(dt, r.spec),
                 block_n=r.block_n)
         return cls(op, space, mode, G_re, G_im)
@@ -187,9 +187,7 @@ class GramOperator:
         like :meth:`FFTMatvec.matmat`."""
         if self.mesh is None:
             plan = self.plan()
-            y = pipeline.run_plan(plan, v,
-                                  self._operands(self.op.F_hat_re,
-                                                 self.op.F_hat_im),
+            y = pipeline.run_plan(plan, v, self._operands(*self.op.planes),
                                   N_t=self.N_t, opts=self.opts)
             return y.astype(self.io_dtype)
 
@@ -201,7 +199,8 @@ class GramOperator:
         operands = self._operands
 
         def body(F_re, F_im, v_loc):
-            y = pipeline.run_plan(plan, v_loc, operands(F_re, F_im),
+            y = pipeline.run_plan(plan, v_loc,
+                                  operands(*op.local_planes(F_re, F_im)),
                                   N_t=N_t, opts=opts)
             return y.astype(io_dtype)
 

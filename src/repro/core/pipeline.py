@@ -135,7 +135,7 @@ class ExecOpts:
     def resolve(self) -> "ResolvedOpts":
         """Bind to the concrete backend (the probe happens here, at
         lowering time; operator construction reads only the spec's
-        ``setup_dtype``)."""
+        ``setup_dtype`` and ``plane_tile``)."""
         spec = resolve_backend(self.backend)
         table = self.dispatch if self.dispatch is not None \
             else default_table(spec)

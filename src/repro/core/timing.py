@@ -29,7 +29,8 @@ from typing import Callable, Optional
 
 import jax
 
-from .fftmatvec import _local_gram, _local_matmat, _local_matvec
+from .fftmatvec import (_local_gram, _local_matmat, _local_matvec,
+                        stored_planes)
 
 VARIANTS = ("matvec", "rmatvec", "matmat", "rmatmat", "gram")
 
@@ -115,21 +116,24 @@ class TimingHarness:
             # contract is tested against.
             if family == "gram":
                 def apply(F_re, F_im, x, *, N_t, cfg, opts, adjoint,
-                          io_dtype):
+                          io_dtype, dims):
                     self.n_traces += 1
+                    F_re, F_im = stored_planes(F_re, F_im, dims)
                     return _local_gram(F_re, F_im, x, N_t, cfg,
                                        opts).astype(io_dtype)
             else:
                 local = _local_matvec if family == "vec" else _local_matmat
 
                 def apply(F_re, F_im, x, *, N_t, cfg, opts, adjoint,
-                          io_dtype):
+                          io_dtype, dims):
                     self.n_traces += 1
+                    F_re, F_im = stored_planes(F_re, F_im, dims)
                     return local(F_re, F_im, x, N_t, cfg, opts,
                                  adjoint).astype(io_dtype)
 
             fn = jax.jit(apply, static_argnames=("N_t", "cfg", "opts",
-                                                 "adjoint", "io_dtype"))
+                                                 "adjoint", "io_dtype",
+                                                 "dims"))
             self._jitted[family] = fn
         return fn
 
@@ -169,6 +173,7 @@ class TimingHarness:
         shared = self._shared(family)
         F_re, F_im = op.F_hat_re, op.F_hat_im
         N_t, cfg, opts, io_dtype = op.N_t, op.precision, op.opts, op.io_dtype
+        dims = op.dims
 
         def call(x):
             # matmat convention (FFTMatvec.matmat): 2-D input is the
@@ -176,7 +181,7 @@ class TimingHarness:
             if family == "mat" and x.ndim == 2:
                 return call(x[..., None])[..., 0]
             return shared(F_re, F_im, x, N_t=N_t, cfg=cfg, opts=opts,
-                          adjoint=adjoint, io_dtype=io_dtype)
+                          adjoint=adjoint, io_dtype=io_dtype, dims=dims)
 
         return call
 

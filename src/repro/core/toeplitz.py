@@ -77,7 +77,8 @@ SETUP_CHUNK_ELEMS = 1 << 25
 
 
 def fourier_block_column(F_col: jax.Array, dtype=None, *,
-                         compute_dtype=None) -> tuple[jax.Array, jax.Array]:
+                         compute_dtype=None,
+                         tile=None) -> tuple[jax.Array, jax.Array]:
     """Phase-0 setup: batched FFT of the zero-padded first block column.
 
     Computed at ``compute_dtype`` — by default the highest available
@@ -94,7 +95,10 @@ def fourier_block_column(F_col: jax.Array, dtype=None, *,
 
     Returns TOSI-layout split planes ``(F_hat_re, F_hat_im)`` each of shape
     (N_t + 1, N_d, N_m) in ``dtype`` (default: the compute dtype) — rfft
-    of length 2*N_t keeps N_t+1 bins.
+    of length 2*N_t keeps N_t+1 bins.  ``tile=(rows, lanes)`` stores them
+    zero-padded to (N_t + 1, rows * ceil(N_d / rows), lanes * ceil(N_m /
+    lanes)) instead: the transform fills the leading (N_d, N_m) of each
+    bin and leaves the rest zero (DESIGN.md §12).
     """
     N_t, N_d, N_m = F_col.shape
     if compute_dtype is None:
@@ -104,11 +108,13 @@ def fourier_block_column(F_col: jax.Array, dtype=None, *,
     out = jax.dtypes.canonicalize_dtype(
         compute if dtype is None else dtype)
     chunk = max(1, min(N_m, SETUP_CHUNK_ELEMS // (N_t * N_d)))
-    return _fourier_planes(F_col, compute, out, chunk)
+    stored = (N_d, N_m) if tile is None else \
+        tuple(-(-n // t) * t for n, t in zip((N_d, N_m), tile))
+    return _fourier_planes(F_col, compute, out, chunk, stored)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _fourier_planes(F_col, compute, out, chunk: int):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _fourier_planes(F_col, compute, out, chunk: int, stored):
     N_t, N_d, N_m = F_col.shape
 
     def planes(cols):
@@ -116,7 +122,7 @@ def _fourier_planes(F_col, compute, out, chunk: int):
         return F_hat.real.astype(out), F_hat.imag.astype(out)
 
     def put(acc, start, cols):
-        return tuple(jax.lax.dynamic_update_slice_in_dim(a, p, start, axis=2)
+        return tuple(jax.lax.dynamic_update_slice(a, p, (0, 0, start))
                      for a, p in zip(acc, planes(cols)))
 
     def body(i, acc):
@@ -124,7 +130,7 @@ def _fourier_planes(F_col, compute, out, chunk: int):
         return put(acc, start,
                    jax.lax.dynamic_slice_in_dim(F_col, start, chunk, axis=2))
 
-    shape = (N_t + 1, N_d, N_m)
+    shape = (N_t + 1, *stored)
     acc = (jnp.zeros(shape, out), jnp.zeros(shape, out))
     n_full, tail = divmod(N_m, chunk)
     acc = jax.lax.fori_loop(0, n_full, body, acc)

@@ -141,7 +141,7 @@ def tile_map_for_operator(op, cfg: PrecisionConfig, tol: float, *,
     the caller can evaluate the matching tile-aware bound (and thread them
     through ``prune_lattice``).
     """
-    w = tile_weights(block_norms(op.F_hat_re, op.F_hat_im, shape))
+    w = tile_weights(block_norms(*op.planes, shape))
     tiles = derive_tile_map(
         cfg, tol, op.N_t, op.N_d, op.N_m, shape=shape, weights=w,
         p_r=p_r, p_c=p_c, adjoint=adjoint, kappa=kappa,

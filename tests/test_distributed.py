@@ -86,6 +86,45 @@ print(json.dumps({"e1": e1, "e2": e2, "e3": e3, "e4": e4, "e5": e5,
     assert res["colls"] == ["all-reduce"]
 
 
+def test_tile_padded_planes_on_a_grid_subprocess():
+    """A backend that tile-pads the planes (``tpu-pallas``) has each device
+    of a 2x4 grid store its block zero-padded to whole tiles; the
+    operator reads the unpadded planes back, and its answers (through the
+    CPU's XLA path) equal those of planes stored unpadded, bit for bit."""
+    res = _run(r"""
+import jax, json
+import jax.numpy as jnp
+import numpy as np
+from repro.core import FFTMatvec, PrecisionConfig, random_block_column
+from repro.jax_compat import make_mesh
+mesh = make_mesh((2, 4), ("row", "col"))
+Nt, Nd, Nm = 16, 6, 128
+cfg = PrecisionConfig.from_string("sssss")
+F_col = random_block_column(jax.random.PRNGKey(0), Nt, Nd, Nm)
+pad = FFTMatvec.from_block_column(F_col, cfg, mesh=mesh, backend="tpu-pallas")
+plain = FFTMatvec.from_block_column(F_col, cfg, mesh=mesh, backend="cpu-xla")
+shards = {s.data.shape for s in pad.F_hat_re.addressable_shards}
+blocks = np.asarray(pad.F_hat_re).reshape(Nt + 1, 2, 8, 4, 128)
+zeros = not blocks[:, :, 3:].any() and not blocks[:, :, :, :, 32:].any()
+same = all(np.array_equal(np.asarray(a), np.asarray(b))
+           for a, b in zip(pad.planes, (plain.F_hat_re, plain.F_hat_im)))
+run = pad.with_backend("cpu-xla")
+m = jax.device_put(jax.random.normal(jax.random.PRNGKey(1), (Nm, Nt)),
+                   pad.m_sharding())
+d = jax.device_put(jax.random.normal(jax.random.PRNGKey(2), (Nd, Nt)),
+                   pad.d_sharding())
+g = run.gram(space="parameter")
+answers = [np.array_equal(np.asarray(a), np.asarray(b)) for a, b in (
+    (run.matvec(m), plain.matvec(m)), (run.rmatvec(d), plain.rmatvec(d)),
+    (g.apply(m), plain.gram(space="parameter").apply(m)))]
+print(json.dumps({"shards": sorted(shards), "dims": [pad.N_d, pad.N_m],
+                  "zeros": bool(zeros), "same": same, "answers": answers}))
+""")
+    assert res["shards"] == [[17, 8, 128]]
+    assert res["dims"] == [6, 128] and res["zeros"] and res["same"]
+    assert res["answers"] == [True, True, True]
+
+
 def test_sharded_train_step_matches_single_device():
     res = _run(r"""
 import jax, json

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.backend import DispatchTable
+from repro.backend.spec import BUILTIN_SPECS
 from repro.core import (ExecOpts, FFTMatvec, PrecisionConfig,
                         dense_from_block_column, dense_matvec, dense_rmatvec,
                         heat_equation_p2o, random_block_column, rel_l2)
@@ -231,3 +232,51 @@ def test_matmat_io_dtype_follows_highest_level():
         op = FFTMatvec.from_block_column(
             F_col, precision=PrecisionConfig.from_string(s))
         assert op.matmat(M).dtype == dt, s
+
+
+# -- tile-padded storage of the F_hat planes ---------------------------------
+
+def _answers(op):
+    """matvec, rmatvec and the exact parameter-space Gram, run through the
+    interpreted Pallas kernels whatever backend ``op`` was built for."""
+    run = op.with_backend("cpu-interpret", DispatchTable(force="pallas"))
+    m = jax.random.normal(jax.random.PRNGKey(22), (op.N_m, op.N_t),
+                          dtype=jnp.float32)
+    d = jax.random.normal(jax.random.PRNGKey(23), (op.N_d, op.N_t),
+                          dtype=jnp.float32)
+    return [np.asarray(y) for y in (run.matvec(m), run.rmatvec(d),
+                                    run.gram(space="parameter").apply(m))]
+
+
+@pytest.mark.parametrize("name", sorted(BUILTIN_SPECS))
+def test_plane_tile_is_what_phase3_reads(name):
+    """Only a compiled Pallas Phase 3, which reads its plane operands in
+    (sublane, lane) tiles, has set-up pad the planes to them."""
+    want = (8, 128) if name == "tpu-pallas" else None
+    assert BUILTIN_SPECS[name].plane_tile == want
+
+
+@pytest.mark.parametrize("cfg", ["sssss", "shhss"])
+def test_tile_padded_planes_give_the_same_answers(cfg):
+    """Set-up on a backend that tile-pads stores the planes zero-padded to
+    whole tiles, ``with_precision`` keeps them so, and the operator's
+    answers are bit-identical to those from planes stored unpadded."""
+    Nt, Nd, Nm = 16, 5, 200
+    F_col = random_block_column(jax.random.PRNGKey(21), Nt, Nd, Nm)
+    top = PrecisionConfig.from_string("sssss")
+    low = PrecisionConfig.from_string(cfg)
+    op = FFTMatvec.from_block_column(F_col, top, backend="tpu-pallas")
+    plain = FFTMatvec(*fourier_block_column(F_col, jnp.float32,
+                                            compute_dtype=jnp.float32),
+                      Nt, top, op.opts)
+    tuned = op.with_precision(low)
+    for o in (op, tuned):
+        assert o.F_hat_re.shape == o.F_hat_im.shape == (Nt + 1, 8, 256)
+        assert (o.N_d, o.N_m) == (Nd, Nm)
+        for p in (o.F_hat_re, o.F_hat_im):
+            pad = np.asarray(p.astype(jnp.float32))
+            assert not pad[:, Nd:].any() and not pad[:, :, Nm:].any()
+    for a, b in zip(op.planes, plain.planes):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(_answers(tuned), _answers(plain.with_precision(low))):
+        np.testing.assert_array_equal(a, b)

@@ -6,7 +6,11 @@ kernels) refuses here what it would refuse on the chip: block shapes not
 aligned to the (8, 128) tiling, too much VMEM, a program that does not
 fit.  Interpret mode checks none of that.  Shapes are the paper's
 single-chip ones (``PAPER_SINGLE``: N_t=1000, N_d=100, N_m=5000; B =
-N_t + 1 frequency bins), and the (104, 5120) tile-padded variant.
+N_t + 1 frequency bins), and the (104, 5120) tile-padded variant.  A
+whole program takes the F_hat planes in the format the program's own
+set-up stores them in on the described chip (``stored_planes``), never a
+layout written here: planes in another layout compile to a relayout copy
+of 4 GB in every program that reads them.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -19,12 +23,15 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.layout import Format, Layout
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.backend import resolve_backend
 from repro.configs.fftmatvec_paper import PAPER_SINGLE
-from repro.core import ExecOpts, FFTMatvec, PrecisionConfig
+from repro.core import ExecOpts, FFTMatvec, PrecisionConfig, fftmatvec
 from repro.kernels import pad_cast, sbgemv
 
 N_T, N_D, N_M = PAPER_SINGLE.N_t, PAPER_SINGLE.N_d, PAPER_SINGLE.N_m
@@ -34,19 +41,24 @@ DTYPES = [jnp.float32, jnp.bfloat16]
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:      # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
 def _arg(shape, dtype, sharding):
-    """A described argument in the row-major layout a device array has."""
+    """A described argument in row-major layout: a kernel's operand, or a
+    program's vector input."""
     layout = Layout(major_to_minor=tuple(range(len(shape))))
     return jax.ShapeDtypeStruct(shape, dtype,
                                 sharding=Format(layout, sharding))
@@ -138,22 +150,119 @@ def test_pad_cast_compiles(one_chip, direction):
     assert _has_kernel(_compile(fn, x))
 
 
-def test_paper_matvec_compiles_with_pallas_phase3(one_chip):
+def _plane_copies(compiled, shape) -> list:
+    """The program's ``copy`` instructions of an operand of ``shape``."""
+    dims = "[" + ",".join(map(str, shape)) + "]"
+    return [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if re.search(r"\bcopy\(", line) and dims in line]
+
+
+@pytest.fixture(scope="module")
+def stored_planes(one_chip):
+    """The F_hat planes at the paper shape as the program stores them on
+    the described chip, by rung: the output formats of the compiled
+    ``tpu-pallas`` set-up (``sssss``) and of ``with_precision``'s cast
+    from there (``shhss``); no layout is written here."""
+    spec = resolve_backend("tpu-pallas")
+    cfg = PrecisionConfig.from_string("sssss")
+    F_col = _arg((N_T, N_D, N_M), jnp.float32, one_chip)
+    with jax.enable_x64(False):
+        setup = jax.jit(fftmatvec._setup(spec, cfg)).lower(F_col)
+        f32 = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=f)
+               for s, f in zip(setup.out_info,
+                               setup.compile().output_formats)]
+        cast = jax.jit(lambda p: p.astype(jnp.bfloat16)).lower(
+            f32[0]).compile()
+    bf16 = jax.ShapeDtypeStruct(f32[0].shape, jnp.bfloat16,
+                                sharding=cast.output_formats)
+    return {"sssss": f32, "shhss": [bf16, bf16]}
+
+
+@pytest.fixture(scope="module")
+def stored_programs(one_chip, stored_planes):
+    """``(cfg, call) -> compiled``: each program that reads the operator at
+    the paper shape, compiled once, with the planes as the program stores
+    them (tile-padded, in the device's default layout for that shape)."""
+    compiled = {}
+
+    def get(cfg, call):
+        if (cfg, call) not in compiled:
+            prec_cfg = PrecisionConfig.from_string(cfg)
+            opts = ExecOpts(backend="tpu-pallas")
+            x = _arg((N_D if call == "rmatmat" else N_M, N_T), jnp.float32,
+                     one_chip)
+
+            def fn(Fr, Fi, x):
+                op = FFTMatvec(Fr, Fi, N_T, prec_cfg, opts,
+                               dims=(N_D, N_M))
+                if call == "gram":
+                    return op.gram(space="parameter", mode="exact").apply(x)
+                return getattr(op, call)(x)
+
+            compiled[cfg, call] = _compile(fn, *stored_planes[cfg], x)
+        return compiled[cfg, call]
+
+    return get
+
+
+def test_paper_matvec_compiles_with_pallas_phase3(stored_programs):
     """One whole ``sssss`` matvec at the paper shape: Phase 3 is a Pallas
     kernel, and the program reads the stored F_hat planes in place (its
     scratch stays far below one plane: no relayout copy of F_hat)."""
-    cfg = PrecisionConfig.from_string("sssss")
-    opts = ExecOpts(backend="tpu-pallas")
-    F = _arg((B, N_D, N_M), jnp.float32, one_chip)
-    x = _arg((N_M, N_T), jnp.float32, one_chip)
-    fn = lambda Fr, Fi, x: FFTMatvec(Fr, Fi, N_T, cfg, opts).matvec(x)
-    compiled = _compile(fn, F, F, x)
+    compiled = stored_programs("sssss", "matvec")
     kernels = [line for line in compiled.as_text().splitlines()
                if 'custom_call_target="tpu_custom_call"' in line
                and f"[{B},{N_D},{N_M}]" in line]
     assert kernels, "Phase 3 did not compile to a Pallas kernel"
     plane_bytes = B * N_D * N_M * 4
     assert compiled.memory_analysis().temp_size_in_bytes < plane_bytes // 8
+
+
+@pytest.mark.parametrize("cfg,call", [("sssss", "matvec"),
+                                      ("sssss", "rmatmat"),
+                                      ("sssss", "gram"),
+                                      ("shhss", "matvec")])
+def test_programs_read_stored_planes_without_copies(stored_programs, cfg,
+                                                    call):
+    """Each program that reads the operator takes the planes as set-up
+    stored them and hands the Phase-3 kernel a view of them: no copy of a
+    plane, and scratch far below one plane."""
+    compiled = stored_programs(cfg, call)
+    assert not _plane_copies(compiled, (B, N_D, N_M))
+    assert not _plane_copies(compiled, (B, 104, 5120))
+    plane_bytes = B * N_D * N_M * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < plane_bytes // 8
+
+
+def test_mesh_setup_stores_planes_the_kernel_reads(topo):
+    """The mesh set-up (``from_block_column`` on a 1x4 grid) stores each
+    device's planes tile-padded, in the default layout the Phase-3 kernel
+    reads, so the mesh matvec copies none.  At this shape the v5e's
+    default layout of an unpadded block is not row-major, and the kernel
+    would copy it."""
+    n_t, n_d, n_m = 100, 12, 4 * 250
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("row", "col"))
+    cfg = PrecisionConfig.from_string("sssss")
+    spec = resolve_backend("tpu-pallas")
+    F_col = jax.ShapeDtypeStruct(
+        (n_t, n_d, n_m), jnp.float32,
+        sharding=NamedSharding(mesh, P(None, "row", "col")))
+    with jax.enable_x64(False):
+        setup = fftmatvec._setup(spec, cfg, mesh).lower(F_col)
+        formats = setup.compile().output_formats
+    assert all(f.layout.major_to_minor == (0, 1, 2) for f in formats)
+    planes = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=f)
+              for s, f in zip(setup.out_info, formats)]
+    assert planes[0].shape == (n_t + 1, 16, 4 * 256)
+    x = jax.ShapeDtypeStruct((n_m, n_t), jnp.float32,
+                             sharding=NamedSharding(mesh, P("col", None)))
+    fn = lambda Fr, Fi, x: FFTMatvec(
+        Fr, Fi, n_t, cfg, ExecOpts(backend="tpu-pallas"), mesh=mesh,
+        dims=(n_d, n_m)).matvec(x)
+    compiled = _compile(fn, *planes, x)
+    assert _has_kernel(compiled)
+    assert not _plane_copies(compiled, (n_t + 1, n_d, n_m // 4))
+    assert not _plane_copies(compiled, (n_t + 1, 16, 256))
 
 
 @pytest.mark.parametrize("call", ["matvec", "rmatmat", "gram"])
