@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from repro.backend import (BackendSpec, DispatchTable, UnsupportedOnBackend,
                            default_table, resolve_backend)
 from repro.kernels import ops as kops
+from repro.kernels.rounding import store
 from . import precision as prec
 from .precision import PrecisionConfig
 
@@ -263,13 +264,14 @@ def reorder_planes(re, im, level: str, *, to_tosi: bool, S: int = 1):
     """
     dt = prec.real_dtype(level)
     if S == 1:
-        return re.astype(dt).T, im.astype(dt).T
+        return store(re, dt).T, store(im, dt).T
     if to_tosi:
         SR, K = re.shape
         R = SR // S
-        f = lambda x: x.astype(dt).reshape(S, R, K).transpose(2, 1, 0)
+        f = lambda x: store(x, dt).reshape(S, R, K).transpose(2, 1, 0)
     else:
-        f = lambda x: x.astype(dt).transpose(2, 1, 0).reshape(-1, x.shape[0])
+        f = lambda x: store(x, dt).transpose(2, 1, 0).reshape(
+            -1, x.shape[0])
     return f(re), f(im)
 
 
@@ -285,7 +287,7 @@ def _fft(stage, x, operands, N_t, S, opts):
     lvl = stage.level
     v_hat = jnp.fft.rfft(x.astype(prec.fft_compute_dtype(lvl)), axis=-1)
     dt = prec.real_dtype(lvl)
-    return v_hat.real.astype(dt), v_hat.imag.astype(dt)
+    return store(v_hat.real, dt), store(v_hat.imag, dt)
 
 
 def _reorder(stage, x, operands, N_t, S, opts):
@@ -301,18 +303,17 @@ def _gemv(stage, x, operands, N_t, S, opts):
     A_re, A_im = operands[stage.operand]
     dt = prec.real_dtype(stage.level)
     mode = "H" if stage.adjoint else "N"
-    x_re, x_im = (p.astype(dt) for p in x)
+    A_re, A_im, x_re, x_im = (store(p, dt) for p in (A_re, A_im) + x)
     # stage-level dispatch: a forced-Pallas preference relaxes to auto for
     # levels the backend's Pallas cannot run (d stages of the paper ladder
     # on the CPU interpreter keep flowing through XLA)
     table = opts.table.for_dtype(dt, opts.spec)
     if S == 1:
-        return kops.sbgemv(A_re.astype(dt), A_im.astype(dt), x_re, x_im,
-                           mode, out_dtype=dt, backend=opts.spec,
-                           dispatch=table, block_n=opts.block_n,
-                           tile_map=stage.tile_map)
-    return kops.sbgemm(A_re.astype(dt), A_im.astype(dt), x_re, x_im, mode,
-                       out_dtype=dt, backend=opts.spec, dispatch=table,
+        return kops.sbgemv(A_re, A_im, x_re, x_im, mode, out_dtype=dt,
+                           backend=opts.spec, dispatch=table,
+                           block_n=opts.block_n, tile_map=stage.tile_map)
+    return kops.sbgemm(A_re, A_im, x_re, x_im, mode, out_dtype=dt,
+                       backend=opts.spec, dispatch=table,
                        block_n=opts.block_n, block_s=opts.block_s,
                        tile_map=stage.tile_map)
 
@@ -322,7 +323,7 @@ def _ifft(stage, x, operands, N_t, S, opts):
     cdt = prec.complex_dtype(lvl)
     v_hat = x[0].astype(cdt) + 1j * x[1].astype(cdt)
     v = jnp.fft.irfft(v_hat, n=2 * N_t, axis=-1)
-    return v.astype(prec.real_dtype(lvl))
+    return store(v, prec.real_dtype(lvl))
 
 
 def _mask(stage, x, operands, N_t, S, opts):
@@ -441,7 +442,7 @@ def _psum(stage, x, operands, N_t, S, opts):
 
     def reduce_one(p):
         carrier_dt = p.dtype
-        q = p.astype(comm_dt)
+        q = store(p, comm_dt)
         if stage.collective == "hierarchical":
             # fast (minor) tier first, then outward — the executed form of
             # the paper's within-row-then-across-rows blocking

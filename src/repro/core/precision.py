@@ -13,6 +13,10 @@ A configuration is written exactly like the paper's runtime flag, e.g.
 ``-prec dssdd`` -> ``PrecisionConfig.from_string("dssdd")``.  Complex data
 is carried as split re/im planes of the phase's *real* dtype (Pallas TPU
 has no complex dtype; the MXU is a real systolic array) — see DESIGN.md §2.
+A phase that stores at a lower rung than the value it is handed rounds it
+with :func:`repro.kernels.rounding.store` at the rung's
+:func:`real_dtype`: ``lax.reduce_precision``, never a cast there and back,
+which the TPU compiler may drop (DESIGN.md §13).
 """
 
 from __future__ import annotations
@@ -28,7 +32,8 @@ PHASES = ("pad", "fft", "gemv", "ifft", "reduce")
 _LEVELS = ("h", "s", "d")  # ordered low -> high
 _REAL_DTYPE = {"d": jnp.float64, "s": jnp.float32, "h": jnp.bfloat16}
 # FFTs always *compute* in >= f32 (XLA FFT op supports f32/f64 only; TPU FFTs
-# are f32).  "h" phases compute f32 and store bf16 at phase boundaries.
+# are f32).  "h" phases compute f32 and store bf16 at phase boundaries, each
+# store rounded by ``kernels.rounding.store`` (DESIGN.md §13).
 _FFT_COMPUTE_DTYPE = {"d": jnp.float64, "s": jnp.float32, "h": jnp.float32}
 _COMPLEX_DTYPE = {"d": jnp.complex128, "s": jnp.complex64, "h": jnp.complex64}
 
@@ -274,17 +279,3 @@ PAPER_OPT_FSTAR = PrecisionConfig.from_string("ddssd")
 # >=512 GPUs on Frontier: also reduce in low precision (paper §C.1: "dssds").
 PAPER_OPT_F_LARGE = PrecisionConfig.from_string("dssds")
 TPU_OPT_F = PrecisionConfig.from_string("shhss")
-
-
-def cast_to(x, level: str):
-    """Cast an array (or None) to the real dtype of ``level``.
-
-    No-op when the dtype already matches — important so that fused
-    pad+cast kernels don't double-cast.
-    """
-    if x is None:
-        return None
-    dt = real_dtype(level)
-    if x.dtype == dt:
-        return x
-    return x.astype(dt)

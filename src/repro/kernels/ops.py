@@ -41,6 +41,7 @@ from . import ref as _ref
 from . import pad_cast as _pad_cast
 from . import sbgemv as _sbgemv
 from .padding import pad_planes, pad_to_multiple, round_up
+from .rounding import store
 
 # Back-compat alias: the transition point now lives in DispatchTable.
 SHORT_WIDE_RATIO = DEFAULT_SHORT_WIDE_RATIO
@@ -119,7 +120,7 @@ def sbgemv(A_re, A_im, x_re, x_im, mode: str = "N", *, out_dtype=None,
     if path != "pallas":
         fn = _ref.sbgemv_complex_ref if path == "ref" else _sbgemv_xla_fused
         y_re, y_im = fn(A_re, A_im, x_re, x_im, mode)
-        return y_re.astype(out_dtype), y_im.astype(out_dtype)
+        return store(y_re, out_dtype), store(y_im, out_dtype)
 
     # A planes go in as stored (the kernels take any m, n): padding them
     # would copy the whole operator on every call
@@ -132,7 +133,7 @@ def sbgemv(A_re, A_im, x_re, x_im, mode: str = "N", *, out_dtype=None,
         y_re, y_im = _sbgemv.sbgemv_th_complex(A_re, A_im, x_re, x_im,
                                                conj=(mode == "H"),
                                                block_n=bn, interpret=itp)
-    return y_re.astype(out_dtype), y_im.astype(out_dtype)
+    return store(y_re, out_dtype), store(y_im, out_dtype)
 
 
 def sbgemv_real(A, x, mode: str = "N", *, out_dtype=None,
@@ -149,12 +150,12 @@ def sbgemv_real(A, x, mode: str = "N", *, out_dtype=None,
     spec, table = resolve_backend_dispatch(backend, dispatch)
     path = table.gemv_path(m, n, mode, A.dtype, spec)
     if path != "pallas":
-        return _ref.sbgemv_real_ref(A, x, mode).astype(out_dtype)
+        return store(_ref.sbgemv_real_ref(A, x, mode), out_dtype)
 
     bn = min(block_n or spec.default_block_n, max(spec.lane, n))
     itp = spec.pallas_interpret
     fn = _sbgemv.sbgemv_n_real if mode == "N" else _sbgemv.sbgemv_th_real
-    return fn(A, x, block_n=bn, interpret=itp).astype(out_dtype)
+    return store(fn(A, x, block_n=bn, interpret=itp), out_dtype)
 
 
 def pad_cast(x, pad_to: int, out_dtype, *, backend=None, dispatch=None,
@@ -294,7 +295,7 @@ def sbgemm(A_re, A_im, X_re, X_im, mode: str = "N", *, out_dtype=None,
             fn = (_ref.sbgemm_complex_ref if path == "ref"
                   else _sbgemm_xla_fused)
             Y_re, Y_im = fn(A_re, A_im, X_re, X_im, mode)
-        return Y_re.astype(out_dtype), Y_im.astype(out_dtype)
+        return store(Y_re, out_dtype), store(Y_im, out_dtype)
 
     bn = min(block_n or spec.default_block_n, max(spec.lane, n))
     bs = min(block_s or spec.default_block_s, round_up(S, spec.sublane))
@@ -323,8 +324,7 @@ def sbgemm(A_re, A_im, X_re, X_im, mode: str = "N", *, out_dtype=None,
             Y_re, Y_im = _sbgemv.sbgemm_th_complex(
                 A_re, A_im, Xr, Xi, conj=(mode == "H"), block_n=bn,
                 block_s=bs, interpret=itp)
-    Y_re, Y_im = Y_re[..., :S], Y_im[..., :S]
-    return Y_re.astype(out_dtype), Y_im.astype(out_dtype)
+    return store(Y_re[..., :S], out_dtype), store(Y_im[..., :S], out_dtype)
 
 
 def sbgemm_gram(A_re, A_im, *, space: str = "parameter", out_dtype=None,
@@ -386,7 +386,7 @@ def sbgemm_gram(A_re, A_im, *, space: str = "parameter", out_dtype=None,
     # enforce exact Hermitian symmetry (kills accumulation-order roundoff)
     G_re = 0.5 * (G_re + G_re.transpose(0, 2, 1))
     G_im = 0.5 * (G_im - G_im.transpose(0, 2, 1))
-    return G_re.astype(out_dtype), G_im.astype(out_dtype)
+    return store(G_re, out_dtype), store(G_im, out_dtype)
 
 
 def sbgemm_real(A, X, mode: str = "N", *, out_dtype=None,
@@ -401,9 +401,9 @@ def sbgemm_real(A, X, mode: str = "N", *, out_dtype=None,
     path = table.gemv_path(m, n, mode, A.dtype, spec)
     if path != "pallas":
         if tile_map is not None:
-            return _ref.sbgemm_tiled_real_ref(A, X, tile_map,
-                                              mode).astype(out_dtype)
-        return _ref.sbgemm_real_ref(A, X, mode).astype(out_dtype)
+            return store(_ref.sbgemm_tiled_real_ref(A, X, tile_map, mode),
+                         out_dtype)
+        return store(_ref.sbgemm_real_ref(A, X, mode), out_dtype)
 
     bn = min(block_n or spec.default_block_n, max(spec.lane, n))
     bs = min(block_s or spec.default_block_s, round_up(S, spec.sublane))
@@ -428,4 +428,4 @@ def sbgemm_real(A, X, mode: str = "N", *, out_dtype=None,
         else:
             Y = _sbgemv.sbgemm_th_real(A, X2, block_n=bn, block_s=bs,
                                        interpret=itp)
-    return Y[..., :S].astype(out_dtype)
+    return store(Y[..., :S], out_dtype)

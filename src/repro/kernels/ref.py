@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .rounding import store
+
 _TILE_LEVEL_INDEX = {"h": 0, "s": 1, "d": 2}
 
 
@@ -32,7 +34,7 @@ def sbgemv_real_ref(A, x, mode: str = "N"):
         y = _einsum("bmn,bm->bn", A.astype(acc), x.astype(acc))
     else:
         raise ValueError(f"bad mode {mode!r}")
-    return y.astype(A.dtype)
+    return store(y, A.dtype)
 
 
 def sbgemv_complex_ref(A_re, A_im, x_re, x_im, mode: str = "N"):
@@ -55,7 +57,7 @@ def sbgemv_complex_ref(A_re, A_im, x_re, x_im, mode: str = "N"):
         y_im = _einsum("bmn,bm->bn", Ar, xi) - _einsum("bmn,bm->bn", Ai, xr)
     else:
         raise ValueError(f"bad mode {mode!r}")
-    return y_re.astype(A_re.dtype), y_im.astype(A_re.dtype)
+    return store(y_re, A_re.dtype), store(y_im, A_re.dtype)
 
 
 def pad_cast_ref(x, pad_to: int, out_dtype):
@@ -63,13 +65,13 @@ def pad_cast_ref(x, pad_to: int, out_dtype):
     (..., pad_to).  Fused Phase-1 memory op."""
     T = x.shape[-1]
     pad = [(0, 0)] * (x.ndim - 1) + [(0, pad_to - T)]
-    return jnp.pad(x.astype(out_dtype), pad)
+    return jnp.pad(store(x, out_dtype), pad)
 
 
 def unpad_cast_ref(x, keep: int, out_dtype):
     """Slice the first ``keep`` entries of the minor axis and cast.  Fused
     Phase-5 memory op."""
-    return x[..., :keep].astype(out_dtype)
+    return store(x[..., :keep], out_dtype)
 
 
 def sbgemm_real_ref(A, X, mode: str = "N"):
@@ -85,7 +87,7 @@ def sbgemm_real_ref(A, X, mode: str = "N"):
         Y = _einsum("bmn,bms->bns", A.astype(acc), X.astype(acc))
     else:
         raise ValueError(f"bad mode {mode!r}")
-    return Y.astype(A.dtype)
+    return store(Y, A.dtype)
 
 
 def sbgemm_gram_ref(A_re, A_im, space: str = "parameter"):
@@ -111,7 +113,7 @@ def sbgemm_gram_ref(A_re, A_im, space: str = "parameter"):
         G_im = e(Ai, Ar) - e(Ar, Ai)
     else:
         raise ValueError(f"bad gram space {space!r}")
-    return G_re.astype(A_re.dtype), G_im.astype(A_re.dtype)
+    return store(G_re, A_re.dtype), store(G_im, A_re.dtype)
 
 
 def sbgemm_complex_ref(A_re, A_im, X_re, X_im, mode: str = "N"):
@@ -136,7 +138,7 @@ def sbgemm_complex_ref(A_re, A_im, X_re, X_im, mode: str = "N"):
     else:
         Y_re = e(Ar, Xr) - e(Ai, Xi)
         Y_im = e(Ar, Xi) + e(Ai, Xr)
-    return Y_re.astype(A_re.dtype), Y_im.astype(A_re.dtype)
+    return store(Y_re, A_re.dtype), store(Y_im, A_re.dtype)
 
 
 # -- tile-centric mixed precision (DESIGN.md §8) ----------------------------
@@ -173,13 +175,14 @@ def quantize_tile_planes(lvl_idx, *planes):
     :func:`expand_tile_levels`; it broadcasts over the row axis m.  A
     round-trip through a dtype at or above the carrier is the identity
     (the ladder's mantissas nest: bf16 ⊂ f32 ⊂ f64), so only genuinely
-    lower tiles lose bits.
+    lower tiles lose bits.  The rounding is :func:`store`'s, which the TPU
+    compiler keeps (DESIGN.md §13).
     """
     sel = jnp.asarray(lvl_idx)[:, None, :]
     outs = []
     for A in planes:
-        q_h = A.astype(jnp.bfloat16).astype(A.dtype)
-        q_s = A.astype(jnp.float32).astype(A.dtype)
+        q_h = store(A, jnp.bfloat16).astype(A.dtype)
+        q_s = store(A, jnp.float32).astype(A.dtype)
         outs.append(jnp.where(sel == 0, q_h, jnp.where(sel == 1, q_s, A)))
     return outs[0] if len(outs) == 1 else tuple(outs)
 

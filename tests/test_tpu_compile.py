@@ -234,6 +234,80 @@ def test_programs_read_stored_planes_without_copies(stored_programs, cfg,
     assert compiled.memory_analysis().temp_size_in_bytes < plane_bytes // 8
 
 
+def _entry(text) -> dict:
+    """{name: line} of each instruction of the compiled entry computation."""
+    body = text[text.index("\nENTRY"):]
+    body = body[:body.index("\n}")]
+    lines = {}
+    for line in body.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+) = ", line)
+        if m:
+            lines[m.group(1)] = line
+    return lines
+
+
+def _called(text, line) -> str:
+    """The body of the computation a fusion calls ('' for other ops)."""
+    m = re.search(r"calls=%?([\w.\-]+)", line)
+    if not m:
+        return ""
+    start = text.index(f"\n%{m.group(1)} ")
+    return text[start:text.index("\n}", start)]
+
+
+def _phase3_to_ifft(text) -> list:
+    """The entry instructions that carry Phase 3's output into the IFFT:
+    from the SBGEMV kernel through every user, up to and including the
+    first matmul fusions of the IFFT (a length-2000 FFT is a DFT matmul
+    on the v5e)."""
+    entry = _entry(text)
+    reached = [n for n, line in entry.items() if n.startswith("sbgemv_")
+               and 'custom_call_target="tpu_custom_call"' in line]
+    assert reached, "Phase 3 did not compile to a Pallas kernel"
+    frontier = list(reached)
+    while frontier:
+        name = frontier.pop()
+        if "kind=kOutput" in entry[name]:
+            continue                    # the IFFT's matmul: stop here
+        for user, line in entry.items():
+            if user not in reached and re.search(
+                    rf"%{re.escape(name)}[,)]", line.split(" = ", 1)[1]):
+                reached.append(user)
+                frontier.append(user)
+    return [entry[n] for n in reached]
+
+
+def _rounds_to_bf16(text, line) -> bool:
+    """The instruction's value is stored as bf16, or it rounds to bf16's
+    8 exponent and 7 mantissa bits in a form the compiler keeps."""
+    rounding = re.compile(r"reduce-precision\([^)]*\), exponent_bits=8, "
+                          r"mantissa_bits=7")
+    result = line.split(" = ", 1)[1].split("(", 1)[0]
+    return "bf16[" in result or bool(rounding.search(_called(text, line))
+                                     or rounding.search(line))
+
+
+def test_shhss_matvec_rounds_phase3_output_to_bf16(stored_programs):
+    """``shhss`` states Phase 3's output at bf16 and the IFFT at f32.  On
+    the way from the kernel to the IFFT the value is stored as bf16 or
+    passes a ``reduce-precision`` to bf16's bits.  A cast down and back up
+    in one fusion is not enough: allowed excess precision, the TPU
+    compiler may drop that pair and carry the kernel's f32 on."""
+    text = stored_programs("shhss", "matvec").as_text()
+    path = _phase3_to_ifft(text)
+    assert any("kind=kOutput" in line for line in path), \
+        "no IFFT matmul follows Phase 3"
+    assert any(_rounds_to_bf16(text, line) for line in path), \
+        [line.strip()[:100] for line in path]
+
+
+@pytest.mark.parametrize("call", ["matvec", "rmatmat", "gram"])
+def test_sssss_programs_round_nowhere(stored_programs, call):
+    """At ``sssss`` no stage boundary narrows, so no program gains a
+    rounding op (its plane copies: the test above)."""
+    assert "reduce-precision(" not in stored_programs("sssss", call).as_text()
+
+
 def test_mesh_setup_stores_planes_the_kernel_reads(topo):
     """The mesh set-up (``from_block_column`` on a 1x4 grid) stores each
     device's planes tile-padded, in the default layout the Phase-3 kernel
@@ -293,14 +367,8 @@ def test_kernels_and_fusions_carry_stage_scopes(one_chip, call):
              or re.search(r"\sfusion\(", line)]
     assert timed
     scoped = re.compile(r'op_name="[^"]*/fftmatvec/[a-z_]+/')
-
-    def body(line):             # a fusion without metadata: what it calls
-        m = re.search(r"calls=%?([\w.\-]+)", line)
-        if not m:
-            return ""
-        start = text.index(f"%{m.group(1)} ")
-        return text[start:text.index("\n}", start)]
-
+    # a fusion without metadata is judged by what it calls
     bare = [line.strip()[:100] for line in timed
-            if not scoped.search(line) and not scoped.search(body(line))]
+            if not scoped.search(line)
+            and not scoped.search(_called(text, line))]
     assert not bare
